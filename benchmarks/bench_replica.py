@@ -184,7 +184,6 @@ def _bench_reshard(data, params, n_queries: int, single) -> dict:
 
 
 def run(full: bool = False, chaos: bool = False) -> dict:
-    jax.config.update("jax_platform_name", "cpu")
     scale = 0.3 if full else 0.05
     n_queries = 256 if full else 48
     data = make_dataset("cora", seed=0, scale=scale)

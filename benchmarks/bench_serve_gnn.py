@@ -530,7 +530,6 @@ def _merge_results(section: str, payload: dict) -> Path:
 def run_slo(full: bool = False) -> dict:
     """Standalone ``--slo`` entry: cost calibration + the cost-budget/SLO
     overload scenario only, merged into the existing results JSON."""
-    jax.config.update("jax_platform_name", "cpu")
     scale = 1.0 if full else 0.15
     batch = 32 if full else 16
     hidden = 64 if full else 32
@@ -551,7 +550,6 @@ def run_slo(full: bool = False) -> dict:
 def run_tenants(full: bool = False) -> dict:
     """Standalone ``--tenants`` entry: the overload scenario only, merged
     into the existing results JSON."""
-    jax.config.update("jax_platform_name", "cpu")
     scale = 1.0 if full else 0.15
     batch = 32 if full else 16
     hidden = 64 if full else 32
@@ -573,7 +571,6 @@ def run_kernels(full: bool = False) -> dict:
     """Standalone ``--kernels`` entry: the fused-vs-unfused launch footprint
     and the multi-bucket co-launch comparison only, merged into the
     existing results JSON."""
-    jax.config.update("jax_platform_name", "cpu")
     scale = 1.0 if full else 0.15
     batch = 32 if full else 16
     hidden = 64 if full else 32
@@ -593,7 +590,6 @@ def run_kernels(full: bool = False) -> dict:
 
 
 def run(full: bool = False) -> dict:
-    jax.config.update("jax_platform_name", "cpu")
     scale = 1.0 if full else 0.15
     n_queries = 1000 if full else 200
     batch = 32 if full else 16

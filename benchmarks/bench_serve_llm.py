@@ -120,7 +120,6 @@ def _bench_family(kind: str, n_queries: int, batch: int, max_new: int,
 
 
 def run(full: bool = False) -> dict:
-    jax.config.update("jax_platform_name", "cpu")
     n_queries = 64 if full else 24
     batch = 8 if full else 4
     max_new = 16 if full else 8

@@ -283,9 +283,7 @@ def run(full: bool = False, executor: str = "host",
         pipeline: bool = False) -> dict:
     # the SPMD comparison needs P host devices; only effective when jax has
     # not initialized a backend yet (standalone runs) — otherwise the SPMD
-    # columns degrade to None and the host columns still emit. The CPU pin
-    # must precede ensure_host_devices (it initializes the backend).
-    jax.config.update("jax_platform_name", "cpu")
+    # columns degrade to None and the host columns still emit.
     from repro.launch.mesh import ensure_host_devices
     spmd_ok = ensure_host_devices(max(SHARD_COUNTS))
     if executor == "spmd" and not spmd_ok:

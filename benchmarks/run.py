@@ -13,17 +13,10 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--only", default="")
-    ap.add_argument("--xla-tuned", action="store_true",
-                    help="set the XLA latency-hiding/async-collective flags "
-                         "before backend init (no-op if XLA_FLAGS is set)")
     args = ap.parse_args()
 
-    if args.xla_tuned:
-        # must run before the section imports below pull in jax — XLA only
-        # reads the flags at backend init
-        from repro.env import xla_tuned
-        xla_tuned()
-
+    from repro.env import enable_compile_cache
+    enable_compile_cache()
     from . import (bench_fig4, bench_gnn_tables, bench_grad_compress,
                    bench_memory, bench_replica, bench_serve_gnn,
                    bench_serve_llm, bench_sharded_serve)

@@ -11,11 +11,13 @@ import numpy as np
 from repro.core import bitops, frdc
 from repro.core.binarize import BinTensor
 from repro.core.bspmm import bspmm
+from repro.env import enable_compile_cache
 from repro.graphs import partition
 from repro.graphs.datasets import make_dataset
 
 
 def main():
+    enable_compile_cache()
     d = make_dataset("cora", seed=0, scale=0.3)
     n_shards = 4
     shards = partition.partition_rows(d.edges[0], d.edges[1], d.n_nodes,

@@ -9,11 +9,13 @@ import numpy as np
 
 from repro.core import abstraction, frdc
 from repro.core.bmm import quantize_weight
+from repro.env import enable_compile_cache
 from repro.graphs.datasets import make_dataset
 from repro.models import gnn
 
 
 def main():
+    enable_compile_cache()
     # 1. a stat-matched synthetic Cora + a trained(-ish) GCN
     d = make_dataset("cora", seed=0, scale=0.25)
     adj = frdc.gcn_normalized(d.edges[0], d.edges[1], d.n_nodes)   # exact D^-1/2(A+I)D^-1/2

@@ -47,6 +47,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import frdc
+from repro.env import enable_compile_cache
 from repro.graphs.datasets import make_dataset
 from repro.models import gnn
 from repro.serve import (AdmissionController, CostEstimator, GNNServeEngine,
@@ -63,13 +64,13 @@ def _report(tag: str, snap: dict) -> None:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--queries", type=int, default=200)
     ap.add_argument("--scale", type=float, default=0.2)
     ap.add_argument("--batch", type=int, default=16)
     ap.add_argument("--epochs", type=int, default=30)
     args = ap.parse_args()
-    jax.config.update("jax_platform_name", "cpu")
 
     # 1. graph + briefly-trained binary GCN ---------------------------------
     d = make_dataset("cora", seed=0, scale=args.scale)

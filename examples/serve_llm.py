@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, reduced_config
+from repro.env import enable_compile_cache
 from repro.models import transformer
 from repro.quant.binary_linear import quantize_params, quantized_param_bytes
 from repro.serve.token_engine import TokenServeEngine
@@ -38,6 +39,7 @@ def direct_reference(cfg, params, prompt, max_new):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--requests", type=int, default=6)
     ap.add_argument("--max-new", type=int, default=12)

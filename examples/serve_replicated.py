@@ -39,6 +39,7 @@ import time
 import jax
 import numpy as np
 
+from repro.env import enable_compile_cache
 from repro.graphs.datasets import make_dataset
 from repro.models import gnn
 from repro.serve import (FaultInjector, FrontDoor, GraphStore,
@@ -58,6 +59,7 @@ def replay_bit_exact(engine, single) -> bool:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--replicas", type=int, default=2)
     ap.add_argument("--shards", type=int, default=2)
@@ -65,7 +67,6 @@ def main() -> None:
     ap.add_argument("--scale", type=float, default=0.1)
     ap.add_argument("--batch", type=int, default=8)
     args = ap.parse_args()
-    jax.config.update("jax_platform_name", "cpu")
 
     # 1. replica tier ------------------------------------------------------
     d = make_dataset("cora", seed=0, scale=args.scale)

@@ -46,6 +46,7 @@ import time
 import jax
 import numpy as np
 
+from repro.env import enable_compile_cache
 from repro.graphs.datasets import make_dataset
 from repro.launch.mesh import make_shard_mesh
 from repro.models import gnn
@@ -55,13 +56,13 @@ from repro.serve import (AdmissionController, GraphStore,
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--shards", type=int, default=4)
     ap.add_argument("--queries", type=int, default=200)
     ap.add_argument("--scale", type=float, default=0.2)
     ap.add_argument("--batch", type=int, default=16)
     args = ap.parse_args()
-    jax.config.update("jax_platform_name", "cpu")
 
     # 1. graph + model -------------------------------------------------------
     d = make_dataset("cora", seed=0, scale=args.scale)

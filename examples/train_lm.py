@@ -14,6 +14,7 @@ import jax.numpy as jnp
 
 from repro.configs import get_config
 from repro.data.pipeline import PrefetchLoader, SyntheticLM
+from repro.env import enable_compile_cache
 from repro.models import transformer
 from repro.optim.optimizer import AdamW, cosine_schedule
 from repro.train.train_step import make_train_step
@@ -21,6 +22,7 @@ from repro.train.trainer import Trainer, TrainerConfig, run_with_restarts
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--layers", type=int, default=6)

@@ -9,11 +9,13 @@ import jax.numpy as jnp
 
 from repro.core import abstraction, frdc, tuner
 from repro.core.bmm import quantize_weight
+from repro.env import enable_compile_cache
 from repro.graphs.datasets import make_dataset
 from repro.models import gnn
 
 
 def main():
+    enable_compile_cache()
     d = make_dataset("cora", seed=0, scale=0.2)
     adj = frdc.gcn_normalized(d.edges[0], d.edges[1], d.n_nodes)
     adj_bin = d.adjacency("binary")

@@ -91,7 +91,10 @@ def _spmm_fp(adj: FRDCMatrix, x: jax.Array) -> jax.Array:
     nbr = group_neighbor_ids(adj.col_idx)          # (G, 32)
     xg = xp[nbr]                                   # (G, 32, F)
     mask = _mask_from_words(coarsen_groups(adj.tiles)).astype(x.dtype)
-    contrib = jnp.einsum("gkn,gnf->gkf", mask, xg)
+    # HIGHEST: a TPU's default precision would round xg to bf16; the {0,1}
+    # mask product is exact in f32, as in the Pallas kernel
+    contrib = jnp.einsum("gkn,gnf->gkf", mask, xg,
+                         precision=jax.lax.Precision.HIGHEST)
     out = _segment_rows(contrib, adj)
     if adj.row_scale is not None:
         out = out * adj.row_scale[:, None].astype(out.dtype)

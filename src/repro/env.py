@@ -1,55 +1,36 @@
-"""Opt-in XLA process-environment tuning (latency hiding, async collectives).
+"""Process-environment setup shared by the entry points.
 
-XLA only reads ``XLA_FLAGS`` when the backend initializes, so these flags
-must land in the environment BEFORE anything imports a jax backend — which
-is why this module imports no jax and the benchmark harness calls
-:func:`xla_tuned` before loading its sections. The flag set follows the
-jax GPU performance guidance (latency-hiding scheduler + async collectives
-+ priority async stream): it lets the scheduler overlap the serve path's
-halo collectives and kernel DMA with compute, which is exactly the
-overlap the multi-bucket co-launch and the fused per-layer kernels are
-shaped for. Harmless off-GPU — unknown ``--xla_gpu_*`` flags are ignored
-by the CPU/TPU backends.
-
-Deliberately OPT-IN and never overriding: a user-set ``XLA_FLAGS`` wins
-unconditionally (their tuning, not ours), and a backend that already
-initialized makes the write a silent no-op, so we refuse and warn instead
-of pretending the flags took effect.
+JAX's persistent compilation cache lets a process reuse the executables an
+earlier process compiled. Its location is part of every entry's key, so it
+must be a fixed path: ``JAX_COMPILATION_CACHE_DIR`` when the environment
+sets it (JAX reads that variable itself, so nothing else is set), else
+``<checkout>/.jax_cache``. Entry points (``chip_smoke.py``, the examples,
+``benchmarks/run.py``) call :func:`enable_compile_cache`; tests never do.
 """
 from __future__ import annotations
 
 import os
-import sys
-import warnings
+from pathlib import Path
+from typing import Mapping, Tuple
 
-XLA_TUNED_FLAGS = (
-    "--xla_gpu_enable_latency_hiding_scheduler=true",
-    "--xla_gpu_enable_async_collectives=true",
-    "--xla_gpu_enable_highest_priority_async_stream=true",
-)
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+# <checkout>/src/repro/env.py -> <checkout>/.jax_cache
+DEFAULT_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
 
 
-def _backend_initialized() -> bool:
-    """Whether a jax backend already exists in this process (best effort:
-    the bridge module's backend cache is non-empty)."""
-    bridge = sys.modules.get("jax._src.xla_bridge")
-    return bool(getattr(bridge, "_backends", None))
+def compile_cache_dir(env: Mapping[str, str] = os.environ
+                      ) -> Tuple[Path, bool]:
+    """The cache directory and whether the environment chose it."""
+    if env.get(ENV_VAR):
+        return Path(env[ENV_VAR]), True
+    return DEFAULT_DIR, False
 
 
-def xla_tuned(env: dict = os.environ) -> bool:
-    """Install :data:`XLA_TUNED_FLAGS` into ``env``; True when applied.
-
-    No-op returning False when ``XLA_FLAGS`` is already set (the user's
-    flags win) or when a jax backend has already initialized (the flags
-    could no longer take effect — warns, so a mis-ordered call site is
-    loud rather than silently untuned)."""
-    if env.get("XLA_FLAGS"):
-        return False
-    if _backend_initialized():
-        warnings.warn(
-            "repro.env.xla_tuned() called after jax backend init; "
-            "XLA_FLAGS would be ignored — call it before importing "
-            "anything that touches jax", RuntimeWarning, stacklevel=2)
-        return False
-    env["XLA_FLAGS"] = " ".join(XLA_TUNED_FLAGS)
-    return True
+def enable_compile_cache(env: Mapping[str, str] = os.environ) -> Path:
+    """Point JAX's persistent compilation cache at :func:`compile_cache_dir`.
+    Returns the directory in use."""
+    path, from_env = compile_cache_dir(env)
+    if not from_env:
+        import jax
+        jax.config.update("jax_compilation_cache_dir", str(path))
+    return path

@@ -1,3 +1,14 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas TPU kernels for the paper's hot spots (BSpMM, BMM, bit packing)."""
+from __future__ import annotations
+
+from typing import Optional
+
+import jax
+
+
+def resolve_interpret(interpret: Optional[bool] = None) -> bool:
+    """Kernels compile natively on a TPU backend and run in Pallas
+    interpret mode anywhere else; ``None`` picks by the default backend."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return bool(interpret)

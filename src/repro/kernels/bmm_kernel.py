@@ -16,10 +16,13 @@ TM*Wk*4 + TN*Wk*4 + TM*TN*4 bytes (< 1.5 MB for K=20480).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from . import resolve_interpret
 
 WORD = 32
 
@@ -58,7 +61,7 @@ def _bmm_xnor_bin_kernel(a_ref, b_ref, o_ref, *, n_bits: int):
                                              "block_n", "interpret"))
 def bmm_xnor(a_packed: jax.Array, b_packed: jax.Array, n_bits: int,
              binarize: bool = False, block_m: int = 128, block_n: int = 128,
-             interpret: bool = True) -> jax.Array:
+             interpret: Optional[bool] = None) -> jax.Array:
     """sign(X) @ sign(W) on packed operands.
 
     a_packed: (M, Wk) uint32; b_packed: (N, Wk) uint32 (weight transposed).
@@ -89,7 +92,7 @@ def bmm_xnor(a_packed: jax.Array, b_packed: jax.Array, n_bits: int,
                   pl.BlockSpec((bn, wk), lambda i, j: (j, 0))],
         out_specs=out_spec,
         out_shape=out_shape,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(a_p, b_p)
     if not binarize:
         return out[:m, :n]

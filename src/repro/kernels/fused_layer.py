@@ -17,9 +17,10 @@ activation — as ONE ``pallas_call``:
     aggregation stages expressed as VALUE-level group walks that a kernel
     body can trace (a ``pallas_call`` cannot nest another one). They walk
     ``grp_ptr`` row ranges and accumulate groups in EXACTLY the kernel
-    order — sequential per tile-row, one ``(TILE, 32) @ (32, F)`` dot or
-    popc per group — so fused results are BITWISE identical to the unfused
-    kernels (both the 1D and 2D grids), not merely close. Scale handling
+    order with the kernel's own Step ③-⑤ helpers — sequential per
+    tile-row, one ``(TILE, 64) @ (64, lanes)`` window dot or popc per
+    group — so fused results are BITWISE identical to the unfused kernels,
+    not merely close. Scale handling
     mirrors ``ops._serve_fp_backend`` / ``ops.serve_fp_pair`` (col scales
     folded into the operand, the shared row scale applied ONCE after the
     intra+halo add).
@@ -30,13 +31,18 @@ key on.
 """
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core.frdc import FRDCMatrix, GROUP, TILE
+from repro.core.frdc import FRDCMatrix, TILE
 
-from .bspmm_kernel import WORD, _bit_transpose, _coarsen_one
+from . import resolve_interpret
+
+from .bspmm_kernel import (SLAB, WORD, _lane_pad, bit_transpose,
+                           coarse_words, group_mask, trinary)
 
 # trace-time counters: [fused kernel launches, fused layers' aggregation
 # calls folded into them] — reset/read by tests and the launch benches.
@@ -53,68 +59,68 @@ def reset_counters() -> None:
 # ---------------------------------------------------------------------------
 
 def _pad_rows(x: jax.Array) -> jax.Array:
-    pad = (-x.shape[0]) % TILE
+    pad = (-x.shape[0]) % SLAB
     return jnp.pad(x, ((0, pad), (0, 0))) if pad else x
 
 
-def _neighbor_rows(col_idx_g: jax.Array) -> jax.Array:
-    """(GROUP,) tile-columns of one group -> (32,) gathered row ids."""
-    offs = jnp.arange(TILE, dtype=col_idx_g.dtype)
-    return (col_idx_g[:, None] * TILE + offs).reshape(-1)
+def _group(adj: FRDCMatrix, x: jax.Array, g):
+    """Group ``g``'s coarse adjacency words and its GATHER gathered window
+    rows — the kernel's Step ②/③ on values."""
+    cols = adj.col_idx[g].astype(jnp.int32)
+    rows = ((cols >> 1)[:, None] * SLAB
+            + jnp.arange(SLAB, dtype=jnp.int32)).reshape(-1)
+    return coarse_words(adj.tiles[g], cols), x[rows]
 
 
-def _walk_fp(adj: FRDCMatrix, x: jax.Array) -> jax.Array:
-    """Raw fp aggregation in kernel order: per tile-row, accumulate the
-    ``grp_ptr`` group range sequentially with the (TILE, 32) mask dot —
-    the same adds in the same order as the Pallas grids, so results are
-    bitwise identical to them."""
+def _walk_rows(adj: FRDCMatrix, width: int, dtype, g_body):
+    """Per tile-row, accumulate ``g_body`` over its ``grp_ptr`` group range
+    sequentially — the kernels' order."""
     n_tr = adj.n_tile_rows
-    f = x.shape[1]
-    k = jnp.arange(GROUP * TILE, dtype=jnp.uint32)
-
-    def g_body(g, acc):
-        a_words = _coarsen_one(adj.tiles[g].astype(jnp.int32)[None])
-        mask = ((a_words[:, None] >> k) & 1).astype(x.dtype)
-        xg = x[_neighbor_rows(adj.col_idx[g])]
-        return acc + jax.lax.dot(mask, xg, preferred_element_type=acc.dtype)
 
     def row_body(r, out):
         acc = jax.lax.fori_loop(adj.grp_ptr[r], adj.grp_ptr[r + 1], g_body,
-                                jnp.zeros((TILE, f), x.dtype))
+                                jnp.zeros((TILE, width), dtype))
         return jax.lax.dynamic_update_slice(out, acc, (r * TILE, 0))
 
     return jax.lax.fori_loop(0, n_tr, row_body,
-                             jnp.zeros((n_tr * TILE, f), x.dtype))
+                             jnp.zeros((n_tr * TILE, width), dtype))
+
+
+def _lanes(x: jax.Array) -> jax.Array:
+    """Zero-pad features to the kernels' lane-padded width (same dot
+    shapes, so the same bits)."""
+    return jnp.pad(x, ((0, 0), (0, _lane_pad(x.shape[1]) - x.shape[1])))
+
+
+def _walk_fp(adj: FRDCMatrix, x: jax.Array) -> jax.Array:
+    """Raw fp aggregation in kernel order: one (TILE, GATHER) mask dot per
+    group, so results are bitwise identical to the Pallas kernel."""
+    xl = _lanes(x)
+
+    def g_body(g, acc):
+        words, xg = _group(adj, xl, g)
+        return acc + jax.lax.dot(group_mask(words, x.dtype), xg,
+                                 precision=jax.lax.Precision.HIGHEST,
+                                 preferred_element_type=acc.dtype)
+
+    return _walk_rows(adj, xl.shape[1], x.dtype, g_body)[:, :x.shape[1]]
 
 
 def _walk_counts(adj: FRDCMatrix, xp: jax.Array, trinary_s2: bool
                  ) -> jax.Array:
     """Raw trinary popc counts in kernel order (integer — exact)."""
-    n_tr = adj.n_tile_rows
     wf = xp.shape[1]
+    xi = jax.lax.bitcast_convert_type(xp, jnp.int32)
 
     def g_body(g, acc):
-        a_words = _coarsen_one(adj.tiles[g].astype(jnp.int32)[None])
-        bt = _bit_transpose(xp[_neighbor_rows(adj.col_idx[g])])    # (wf, 32)
-        rows = []
-        for i in range(TILE):
-            a = a_words[i]
-            if trinary_s2:
-                c = (jax.lax.population_count(a & bt).astype(jnp.int32)
-                     - jax.lax.population_count(a & ~bt).astype(jnp.int32))
-            else:
-                c = (2 * jax.lax.population_count(a & bt).astype(jnp.int32)
-                     - jax.lax.population_count(a).astype(jnp.int32))
-            rows.append(c.reshape(-1))
+        words, xg = _group(adj, xi, g)
+        bt = bit_transpose(
+            lambda c: jax.lax.dynamic_slice_in_dim(xg, c * SLAB, SLAB), wf)
+        rows = [trinary(words[i], words[TILE + i], bt, trinary_s2)
+                .T.reshape(-1) for i in range(TILE)]
         return acc + jnp.stack(rows)
 
-    def row_body(r, out):
-        acc = jax.lax.fori_loop(adj.grp_ptr[r], adj.grp_ptr[r + 1], g_body,
-                                jnp.zeros((TILE, wf * WORD), jnp.int32))
-        return jax.lax.dynamic_update_slice(out, acc, (r * TILE, 0))
-
-    return jax.lax.fori_loop(0, n_tr, row_body,
-                             jnp.zeros((n_tr * TILE, wf * WORD), jnp.int32))
+    return _walk_rows(adj, wf * WORD, jnp.int32, g_body)
 
 
 def agg_fp(adj: FRDCMatrix, x: jax.Array, block_shape=None) -> jax.Array:
@@ -160,7 +166,7 @@ def agg_fp_pair(intra: FRDCMatrix, halo: FRDCMatrix, x_local: jax.Array,
 # The fused-layer runner
 # ---------------------------------------------------------------------------
 
-def fused_call(fn, *args, interpret: bool = True):
+def fused_call(fn, *args, interpret: Optional[bool] = None):
     """Evaluate ``fn(*args)`` as ONE Pallas kernel over whole-array operands.
 
     ``args`` is a pytree whose array leaves become kernel operands (``None``
@@ -202,6 +208,6 @@ def fused_call(fn, *args, interpret: bool = True):
         kernel,
         out_shape=tuple(
             jax.ShapeDtypeStruct(s.shape, s.dtype) for s in out_leaves),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(*operands)
     return jax.tree.unflatten(out_tree, list(out))

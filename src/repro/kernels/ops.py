@@ -9,7 +9,6 @@ from __future__ import annotations
 
 import contextlib
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -36,8 +35,18 @@ def _use_kernels() -> bool:
     return _FORCE_KERNELS or _on_tpu()
 
 
-def _interpret() -> bool:
-    return not _on_tpu()
+def check_fused(fused: bool) -> None:
+    """Refuse the fused per-layer kernel on a TPU backend (session build).
+
+    ``fused_layer.fused_call`` has no grid: it holds whole arrays in VMEM
+    and gathers inside the kernel, which Mosaic cannot compile and a real
+    graph cannot fit. It stays runnable in interpret mode off-TPU."""
+    if fused and _on_tpu():
+        raise NotImplementedError(
+            "fused per-layer kernels (SessionPlan.fused) do not compile "
+            "for a TPU: fused_layer.fused_call has no grid and gathers "
+            "inside one whole-array kernel; build the session with "
+            "fused=False (the unfused Pallas path serves the same bits)")
 
 
 def kernels_active(use_pallas: bool = True) -> bool:
@@ -87,18 +96,12 @@ def launch_stats(fn, *args) -> dict:
     return dict(eqns=eqns, pallas_calls=pallas)
 
 
-def interpret_mode() -> bool:
-    """Interpret flag callers must pass to kernels they launch directly
-    (e.g. ``fused_layer.fused_call``)."""
-    return _interpret()
-
-
 def bmm_xnor(a_packed: jax.Array, b_packed: jax.Array, n_bits: int,
              binarize: bool = False) -> jax.Array:
     """Packed ±1 matmul; kernel on TPU, word-level jnp elsewhere."""
     if _use_kernels():
         return bmm_kernel.bmm_xnor(a_packed, b_packed, n_bits,
-                                   binarize=binarize, interpret=_interpret())
+                                   binarize=binarize)
     out = bitops.bmm_xnor_words(a_packed, b_packed, n_bits)
     if binarize:
         return bitops.pack_bits(out >= 0, axis=-1)
@@ -107,7 +110,7 @@ def bmm_xnor(a_packed: jax.Array, b_packed: jax.Array, n_bits: int,
 
 def binarize_pack(x: jax.Array) -> jax.Array:
     if _use_kernels():
-        return pack_kernel.binarize_pack(x, interpret=_interpret())
+        return pack_kernel.binarize_pack(x)
     return bitops.sign_bits(x, axis=-1)
 
 
@@ -118,8 +121,7 @@ def bspmm_bits(adj: FRDCMatrix, x_packed: jax.Array, n_feat: int,
     if _use_kernels():
         out = bspmm_kernel.bspmm_bits(adj, x_packed, n_feat,
                                       binarize=binarize,
-                                      trinary_mode=trinary_mode,
-                                      interpret=_interpret())
+                                      trinary_mode=trinary_mode)
         return out[: adj.n_rows]
     xt = BinTensor(packed=x_packed, scale=jnp.ones((x_packed.shape[0], 1)),
                    n=n_feat)
@@ -135,8 +137,7 @@ def _serve_fp_backend(adj: FRDCMatrix, x: jax.Array,
     xin = x
     if adj.col_scale is not None:
         xin = xin * adj.col_scale[:, None].astype(x.dtype)
-    out = bspmm_kernel.bspmm_fp(adj, xin, interpret=_interpret(),
-                                block_shape=block_shape)
+    out = bspmm_kernel.bspmm_fp(adj, xin, block_shape=block_shape)
     out = out[: adj.n_rows]
     if adj.row_scale is not None:
         out = out * adj.row_scale[:, None].astype(out.dtype)
@@ -148,7 +149,6 @@ def _serve_bits_backend(adj: FRDCMatrix, x_packed: jax.Array,
     """core.bspmm trinary-counts hook: Pallas BSpMM.BB? raw counts."""
     out = bspmm_kernel.bspmm_bits(adj, x_packed, binarize=False,
                                   trinary_mode=trinary_mode,
-                                  interpret=_interpret(),
                                   block_shape=block_shape)
     return out[: adj.n_rows]
 

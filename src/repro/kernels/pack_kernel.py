@@ -7,10 +7,13 @@ shift/OR (a small reduction along the minor axis — stays in VREGs).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from . import resolve_interpret
 
 WORD = 32
 
@@ -25,7 +28,7 @@ def _pack_kernel(x_ref, o_ref):
 
 @functools.partial(jax.jit, static_argnames=("block_m", "block_f", "interpret"))
 def binarize_pack(x: jax.Array, block_m: int = 256, block_f: int = 1024,
-                  interpret: bool = True) -> jax.Array:
+                  interpret: Optional[bool] = None) -> jax.Array:
     """(M, F) float -> (M, ceil(F/32)) uint32 sign bits (bit=1 iff x>=0).
 
     Padding columns pack as 0 (pad-safety invariant: padded fp values are
@@ -43,7 +46,7 @@ def binarize_pack(x: jax.Array, block_m: int = 256, block_f: int = 1024,
         in_specs=[pl.BlockSpec((bm, bf), lambda i, j: (i, j))],
         out_specs=pl.BlockSpec((bm, bf // WORD), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, fp_ // WORD), jnp.uint32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x_p)
     return out[:m, : (f + WORD - 1) // WORD]
 

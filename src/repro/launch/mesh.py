@@ -10,18 +10,14 @@ from __future__ import annotations
 import jax
 
 
-def _axis_types_kw(n_axes: int) -> dict:
-    """``axis_types`` only exists on newer jax; older versions (<=0.4.x)
-    default every axis to Auto anyway, so omit the kwarg there."""
-    if hasattr(jax.sharding, "AxisType"):
-        return dict(axis_types=(jax.sharding.AxisType.Auto,) * n_axes)
-    return {}
+def _auto_axes(n_axes: int) -> dict:
+    return dict(axis_types=(jax.sharding.AxisType.Auto,) * n_axes)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_types_kw(len(axes)))
+    return jax.make_mesh(shape, axes, **_auto_axes(len(axes)))
 
 
 def make_host_mesh(model: int = 1) -> jax.sharding.Mesh:
@@ -29,7 +25,7 @@ def make_host_mesh(model: int = 1) -> jax.sharding.Mesh:
     n = len(jax.devices())
     data = max(n // model, 1)
     return jax.make_mesh((data, model), ("data", "model"),
-                         **_axis_types_kw(2))
+                         **_auto_axes(2))
 
 
 def make_shard_mesh(n_shards: int):

@@ -17,7 +17,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.configs.base import ModelConfig
 from .layers import _init, linear
 
@@ -210,7 +209,7 @@ def _moe_shard_map(params, x: jax.Array, cfg: ModelConfig):
     adds up per-expert partial outputs. Collectives per layer: ONE (nl, d)
     all-reduce — no token all-gathers.
     """
-    mesh = compat.get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or "model" not in (mesh.axis_names or ()):
         return moe_block(params, x,
                          __import__("dataclasses").replace(cfg, moe_groups=0))
@@ -263,7 +262,7 @@ def _moe_shard_map(params, x: jax.Array, cfg: ModelConfig):
         return jax.lax.psum(out, "model")
 
     flat = x.reshape(n, d)
-    out = compat.shard_map(
+    out = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(dp_axes, None), P(None, None),
                   P("model", None, None), P("model", None, None)),

@@ -15,7 +15,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core import bitops
 
 
@@ -59,5 +58,5 @@ def allreduce_1bit(local_grad: jax.Array, mesh, axis: str = "data"):
         signs = bitops.unpack_pm1(all_packed, n, axis=-1)    # (R, n)
         return jnp.mean(signs * all_scale[:, None], axis=0)
 
-    return compat.shard_map(body, mesh=mesh, in_specs=P(None),
-                            out_specs=P(None), check_vma=False)(local_grad)
+    return jax.shard_map(body, mesh=mesh, in_specs=P(None),
+                         out_specs=P(None), check_vma=False)(local_grad)

@@ -48,6 +48,7 @@ from repro.checkpoint.checkpointer import Checkpointer
 from repro.core import frdc
 from repro.graphs import sampling
 from repro.graphs.datasets import GraphData
+from repro.kernels import ops as kernel_ops
 from repro.serve import adapters, session_core
 from repro.serve.session_core import (  # re-exported (stable import path)
     FAMILIES, FAMILY_AGG_LAYERS, ServeCore, SessionPlan, bucket_pow2)
@@ -148,6 +149,7 @@ class CompiledGraphSession:
                  max_batch: int = 32,
                  adj_full: Optional[Dict[str, frdc.FRDCMatrix]] = None,
                  use_pallas: bool = False, incremental: bool = False):
+        kernel_ops.check_fused(plan.fused)
         self.graph = graph
         self.model = model
         self.plan = plan

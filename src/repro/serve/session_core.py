@@ -183,7 +183,6 @@ def _fused_family_forward(plan: SessionPlan, qparams, x,
     key = {"gcn": None, "sage": "mean", "saint": "sum"}[plan.family]
     mats_src = adjs if key is None else {"adj": adjs[key]}
     arrs = {k: frdc_arrays(m) for k, m in mats_src.items()}
-    interp = kernel_ops.interpret_mode()
 
     h = x
     site = 0
@@ -212,8 +211,7 @@ def _fused_family_forward(plan: SessionPlan, qparams, x,
                 meta["bin_n"] = None
                 return out
 
-            h = fused_layer.fused_call(body, h, bn_stats, arrs,
-                                       interpret=interp)
+            h = fused_layer.fused_call(body, h, bn_stats, arrs)
             site, bin_n = meta["site"], meta["bin_n"]
     return h
 

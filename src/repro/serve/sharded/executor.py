@@ -49,7 +49,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import shard_map
 from repro.core import frdc
 from repro.kernels import ops as kernel_ops
 from repro.serve import session_core
@@ -122,8 +121,7 @@ def _fused_layer_compute(step: LayerStep, trinary_mode: str,
                 y = fused_layer.agg_fp_pair(im, hm, operand, rem_)
         return step.post(aux, y)
 
-    return fused_layer.fused_call(body, st, bn_stats, rem, ia, ha,
-                                  interpret=kernel_ops.interpret_mode())
+    return fused_layer.fused_call(body, st, bn_stats, rem, ia, ha)
 
 
 class _PaddedExecutor(LayerExecutor):
@@ -430,8 +428,9 @@ class SpmdLayerExecutor(_PaddedExecutor):
         out_specs = (PS("data"),) * 3 if calib_bn else PS("data")
         # check_vma=False: pallas_call (the use_pallas backends) has no
         # replication rule; every output is explicitly sharded anyway.
-        fn = jax.jit(shard_map(body, self.mesh, in_specs=tuple(in_specs),
-                               out_specs=out_specs, check_vma=False))
+        fn = jax.jit(jax.shard_map(body, mesh=self.mesh,
+                                   in_specs=tuple(in_specs),
+                                   out_specs=out_specs, check_vma=False))
         self._fns[key] = fn
         return fn
 

@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import shard_map
 from .routing import RoutingTable
 
 
@@ -211,8 +210,8 @@ def mesh_exchange(mesh, blocks: List[np.ndarray], plan: MeshHaloPlan,
     for i in range(p - 1):
         sched += [plan.send_idx[i], plan.recv_pos[i]]
     n_args = 1 + len(sched)
-    out = shard_map(body, mesh, in_specs=(P("data"),) * n_args,
-                    out_specs=P("data"))(stacked, *sched)
+    out = jax.shard_map(body, mesh=mesh, in_specs=(P("data"),) * n_args,
+                        out_specs=P("data"))(stacked, *sched)
     out = np.asarray(out)
     if stats is not None:
         stats.add(tag, plan.payload_bytes(width, dtype.itemsize))

@@ -63,6 +63,7 @@ import numpy as np
 from repro.checkpoint.checkpointer import Checkpointer
 from repro.core import frdc
 from repro.graphs import sampling
+from repro.kernels import ops as kernel_ops
 from repro.launch.mesh import make_shard_mesh
 from repro.serve import adapters, session_core
 from repro.serve.session_core import ServeCore, SessionPlan
@@ -91,6 +92,7 @@ class ShardedGraphSession:
                              f"have {EXECUTORS}")
         if bn_mode not in BN_MODES:
             raise ValueError(f"unknown bn_mode {bn_mode!r}; have {BN_MODES}")
+        kernel_ops.check_fused(plan.fused)
         self.graph = graph
         self.model = model
         self.plan = plan

@@ -2,10 +2,10 @@
 
 The three guarantees of the kernel-plan stack, checked end to end:
 
-  * the 2D (rows, feats) block grid is BITWISE identical to the 1D
-    flattened-group grid for every legal block shape (property sweep over
-    rows/feats/n_feat/tile count; hypothesis widens the sweep when
-    installed);
+  * the (rows, feats) block grid is BITWISE identical to the default
+    block shape for every legal block shape, and matches the dense oracles
+    (property sweep over rows/feats/n_feat/tile count; hypothesis widens
+    the sweep when installed);
   * the fused per-layer path is one Pallas launch per layer and bitwise
     identical to the unfused serve path — verified through the replayed
     ``batch_log`` oracle, which compares jitted vs jitted (the fused
@@ -15,8 +15,8 @@ The three guarantees of the kernel-plan stack, checked end to end:
     bits, visible in the span traces as shared coalesced launch windows.
 
 Plus the persistence seams they ride on: the tuner cache file format and
-``GraphStore`` seeding, the ``SessionPlan.fused`` artifact roundtrip, and
-the ``repro.env`` XLA-flags helper.
+``GraphStore`` seeding, the ``SessionPlan.fused`` artifact roundtrip and
+its refusal on a TPU backend, and the ``repro.env`` compile-cache helper.
 """
 import numpy as np
 import jax
@@ -47,8 +47,9 @@ HIDDEN = 16
 # ---------------------------------------------------------------- 2D grid ---
 
 def _case(seed: int, n: int, f: int, rows: int, feats):
-    """One property-sweep case: the 2D grid must match the 1D grid BITWISE
-    (fp and counts) and the fp oracle to fp tolerance."""
+    """One property-sweep case: a block shape must match the default block
+    BITWISE (fp and counts), the fp oracle to fp tolerance and the counts
+    oracle exactly."""
     rng = np.random.default_rng(seed)
     adj = frdc.from_dense((rng.random((n, n)) < 0.2).astype(np.float32))
     x = jnp.asarray(rng.standard_normal((n, f)), jnp.float32)
@@ -317,20 +318,34 @@ def test_session_plan_fused_roundtrip(data, tmp_path):
     assert not st3.session("g", "gcn").plan.fused
 
 
+def test_fused_refused_on_tpu_at_session_build(data, monkeypatch):
+    """``fused=True`` cannot compile for a TPU (no grid, whole arrays in
+    VMEM, gathers inside the kernel): on a TPU backend the session build
+    raises a clear error instead of failing at first launch and retrying
+    until the queries are shed. Interpret mode keeps it runnable here."""
+    monkeypatch.setattr(kernel_ops, "_on_tpu", lambda: True)
+    st = _store(data, use_pallas=True, fused=True)
+    with pytest.raises(NotImplementedError, match="fused"):
+        st.session("g", "gcn")
+    with pytest.raises(NotImplementedError, match="fused"):
+        st.sharded_session("g", "gcn", 2)
+    # the unfused Pallas path builds on the same backend
+    assert not _store(data, use_pallas=True).session("g", "gcn").plan.fused
+
+
 # ------------------------------------------------------------- env helper ---
 
-def test_xla_tuned_env_helper():
+def test_compile_cache_dir_env_helper(tmp_path):
+    """The persistent compile cache lives where JAX_COMPILATION_CACHE_DIR
+    says; without it at one fixed path inside the checkout (the path is
+    part of every cache key, so it must not move)."""
+    from pathlib import Path
     from repro import env
-    # user flags win: untouched env
-    e = {"XLA_FLAGS": "--user=1"}
-    assert env.xla_tuned(e) is False
-    assert e["XLA_FLAGS"] == "--user=1"
-    # backend already initialized in this test process (jax imported above):
-    # refuses with a warning rather than silently not taking effect
-    jax.devices()
-    with pytest.warns(RuntimeWarning):
-        assert env.xla_tuned({}) is False
-    # the flag set itself is the latency-hiding/async-collective trio
-    joined = " ".join(env.XLA_TUNED_FLAGS)
-    assert "latency_hiding_scheduler" in joined
-    assert "async_collectives" in joined
+    got, from_env = env.compile_cache_dir({env.ENV_VAR: str(tmp_path)})
+    assert (got, from_env) == (tmp_path, True)
+    got, from_env = env.compile_cache_dir({})
+    checkout = Path(env.__file__).resolve().parents[2]
+    assert not from_env
+    assert got == checkout / ".jax_cache" == env.DEFAULT_DIR
+    assert (checkout / "src" / "repro" / "env.py").exists()
+    assert env.compile_cache_dir({env.ENV_VAR: ""}) == (got, False)

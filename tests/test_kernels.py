@@ -22,7 +22,8 @@ def test_bmm_xnor_kernel_matches_ref(m, n, k):
     rng = np.random.default_rng(m * 1000 + n * 10 + k)
     ap, _ = _rand_packed(rng, m, k)
     bp, _ = _rand_packed(rng, n, k)
-    got = bmm_kernel.bmm_xnor(ap, bp, k, block_m=32, block_n=32)
+    got = bmm_kernel.bmm_xnor(ap, bp, k, block_m=32, block_n=32,
+                              interpret=True)
     want = ref.bmm_xnor_ref(ap, bp, k)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
@@ -32,7 +33,8 @@ def test_bmm_xnor_binarize_fused(m, n, k):
     rng = np.random.default_rng(m + n + k)
     ap, _ = _rand_packed(rng, m, k)
     bp, _ = _rand_packed(rng, n, k)
-    got = bmm_kernel.bmm_xnor(ap, bp, k, binarize=True, block_m=32, block_n=32)
+    got = bmm_kernel.bmm_xnor(ap, bp, k, binarize=True, block_m=32,
+                              block_n=32, interpret=True)
     want = ref.bmm_xnor_bin_ref(ap, bp, k)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
@@ -42,7 +44,8 @@ def test_bmm_xnor_binarize_fused(m, n, k):
 def test_binarize_pack_kernel(m, f, dtype):
     rng = np.random.default_rng(m * f)
     x = jnp.asarray(rng.standard_normal((m, f)), dtype)
-    got = pack_kernel.binarize_pack(x, block_m=32, block_f=64)
+    got = pack_kernel.binarize_pack(x, block_m=32, block_f=64,
+                                    interpret=True)
     want = ref.binarize_pack_ref(x)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
@@ -107,16 +110,16 @@ def test_bspmm_dma_start_wait_descriptors_pair():
     """Step-② DMA regression: every started HBM->VMEM gather must be waited
     on with the SAME descriptor (source slice included). Both kernels build
     start and wait through the shared ``_gather_copy`` helper; record its
-    calls per kernel trace and check the wait half mirrors the start half
+    calls per kernel trace and check the wait half mirrors the start halves
     slot for slot — a wait reconstructed from a different source slice
-    (e.g. the old constant ``x_hbm[0:TILE]``) would bypass the helper and
-    break the pairing."""
+    (e.g. a constant ``x_hbm[0:TILE]``) would bypass the helper and break
+    the pattern."""
     calls = []
     real = bspmm_kernel._gather_copy
 
-    def spy(x_hbm, xg_ref, copy_sems, col_idx_ref, g, t):
+    def spy(w, mslot, g, t, slot, fb):
         calls.append(t)
-        return real(x_hbm, xg_ref, copy_sems, col_idx_ref, g, t)
+        return real(w, mslot, g, t, slot, fb)
 
     rng = np.random.default_rng(5)
     adj = frdc.from_dense(_graph(rng, 24, 0.2))
@@ -129,13 +132,15 @@ def test_bspmm_dma_start_wait_descriptors_pair():
         got_bits = bspmm_kernel.bspmm_bits(adj, xp, 32, binarize=False)
     finally:
         bspmm_kernel._gather_copy = real
-    # each kernel-body trace issues GROUP starts then GROUP waits over the
-    # same slot sequence — start/wait pairs match by construction
-    assert calls and len(calls) % (2 * frdc.GROUP) == 0
-    for i in range(0, len(calls), 2 * frdc.GROUP):
-        window = calls[i:i + 2 * frdc.GROUP]
-        assert window[:frdc.GROUP] == window[frdc.GROUP:] \
-            == list(range(frdc.GROUP))
+    # each kernel-body trace of the double-buffered walk issues the first
+    # group's GROUP starts, the next group's GROUP starts, then the current
+    # group's GROUP waits — always over the same slot sequence
+    walk = 3 * frdc.GROUP
+    assert calls and len(calls) % walk == 0
+    for i in range(0, len(calls), walk):
+        window = calls[i:i + walk]
+        for j in range(0, walk, frdc.GROUP):
+            assert window[j:j + frdc.GROUP] == list(range(frdc.GROUP))
     # and the kernels still agree with the oracles through the spy
     np.testing.assert_allclose(np.asarray(got_fp),
                                np.asarray(ref.bspmm_fp_ref(adj, x)),
