@@ -45,7 +45,7 @@ from .gnn_session import CompiledGraphSession, GraphStore
 from .metrics import ServeMetrics
 from .session_core import FAMILY_AGG_LAYERS, launch_prepared_many
 from .slo import SLOTracker
-from .trace import RecompileWatchdog, SpanTracer, TransferWatchdog
+from .trace import RecompileWatchdog, SpanTracer, TransferWatchdog, span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -563,28 +563,30 @@ class GNNServeEngine:
         it, so submit() never blocks on them. A failure after the pop
         requeues the batch at the front of its queue before re-raising:
         queries are never silently lost."""
-        with self._qlock:
-            key = self._pick_queue()
-        if key is None:
-            return None
-        # resolving the session may build/compile it — never under the
-        # lock. The pick stays valid: only this (single) extractor pops,
-        # and new submits are strictly newer than the picked head.
-        session = self._wire_session(self._get_session(key))
-        self._prepare_formation(key, session)
-        with self._qlock:
-            batch = self._pop_batch(key, session)
-            if batch:
-                # virtual-time + backlog accounting of the service start;
-                # with a cost model the virtual charge is the batch's
-                # predicted units, so expensive batches push their tenant
-                # further back than cheap ones of the same size
-                served_cost = None
-                if self.cost is not None:
-                    served_cost = sum(q.cost.units for q in batch
-                                      if q.cost is not None)
-                self.admission.on_served(key[-1], len(batch),
-                                         cost=served_cost)
+        with span("pick"):
+            with self._qlock:
+                key = self._pick_queue()
+            if key is None:
+                return None
+            # resolving the session may build/compile it — never under the
+            # lock. The pick stays valid: only this (single) extractor
+            # pops, and new submits are strictly newer than the picked head.
+            session = self._wire_session(self._get_session(key))
+            self._prepare_formation(key, session)
+            with self._qlock:
+                batch = self._pop_batch(key, session)
+                if batch:
+                    # virtual-time + backlog accounting of the service
+                    # start; with a cost model the virtual charge is the
+                    # batch's predicted units, so expensive batches push
+                    # their tenant further back than cheap ones of the
+                    # same size
+                    served_cost = None
+                    if self.cost is not None:
+                        served_cost = sum(q.cost.units for q in batch
+                                          if q.cost is not None)
+                    self.admission.on_served(key[-1], len(batch),
+                                             cost=served_cost)
         if not batch:
             return None
         t0 = time.perf_counter()
@@ -601,18 +603,19 @@ class GNNServeEngine:
             self._check_fault("extract")
             halo_token = self._trace_halo_begin(session) \
                 if tr is not None else None
-            seeds, result, prepared = self._prepare_stage(session, batch)
-            extract_s = time.perf_counter() - t0
+            with span("extract", tr) as ex:
+                seeds, result, prepared = self._prepare_stage(session,
+                                                              batch)
             if tr is not None:
                 tr.full_cache = prepared is None
                 tr.bucket = self._trace_bucket(prepared)
                 tr.halo = self._trace_halo_end(session, halo_token)
-                tr.span("extract", t0, t0 + extract_s)
             if prepared is not None:
                 self.transfer_watchdog.check_prepared(prepared)
             return _Inflight(key=key, batch=batch, session=session,
                              seeds=seeds, prepared=prepared, result=result,
-                             t_start=t0, extract_s=extract_s, trace=tr)
+                             t_start=t0, extract_s=ex.t1 - ex.t0,
+                             trace=tr)
         except BaseException as e:
             self._requeue(key, batch, stage="extract", error=repr(e))
             self.tracer.commit(tr, error=repr(e), requeued=True)
@@ -632,7 +635,8 @@ class GNNServeEngine:
         this to stage prompt chunks instead of k-hop subgraphs."""
         seeds = np.asarray([q.node for q in batch], np.int64)
         if self._use_full_cache(session):
-            return seeds, session.full_logits()[seeds], None
+            with span("lookup"):
+                return seeds, session.full_logits()[seeds], None
         return seeds, None, session.prepare_batch(seeds)
 
     def _launch_stage(self, inf: _Inflight) -> None:
@@ -643,11 +647,12 @@ class GNNServeEngine:
         (single) successful completion — counting here double-counted
         retried batches and drifted ``cache_hit_rate``."""
         self._check_fault("launch")
-        inf.t_launch = time.perf_counter()
+        with span("launch", inf.trace) as ln:
+            if inf.prepared is not None:
+                inf.devs = inf.session.launch_batch(inf.prepared)
+        inf.t_launch, inf.t_launch_end = ln.t0, ln.t1
         if inf.prepared is not None:
-            inf.devs = inf.session.launch_batch(inf.prepared)
             self.transfer_watchdog.check_launched(inf.devs)
-        inf.t_launch_end = time.perf_counter()
 
     def _launch_coalesced(self, infs: List[_Inflight]) -> None:
         """Multi-bucket COMPUTE head: co-dispatch every deferred batch's
@@ -657,12 +662,12 @@ class GNNServeEngine:
         extract) just get their launch window stamped. A failure requeues
         EVERY deferred batch and drops them from the pipeline, mirroring
         the single-batch launch failure path."""
-        t0 = time.perf_counter()
         device_infs = [inf for inf in infs if inf.prepared is not None]
         try:
             self._check_fault("launch")
-            devs_lists = launch_prepared_many(
-                [inf.prepared for inf in device_infs])
+            with span("launch") as ln:
+                devs_lists = launch_prepared_many(
+                    [inf.prepared for inf in device_infs])
         except BaseException as e:
             for inf in infs:
                 try:
@@ -674,14 +679,21 @@ class GNNServeEngine:
                 self.tracer.commit(inf.trace, error=repr(e), requeued=True)
                 inf.trace = None
             raise
-        t1 = time.perf_counter()
         for inf, devs in zip(device_infs, devs_lists):
             inf.devs = devs
             self.transfer_watchdog.check_launched(inf.devs)
         for inf in infs:
-            inf.t_launch, inf.t_launch_end = t0, t1
+            inf.t_launch, inf.t_launch_end = ln.t0, ln.t1
             inf.coalesced = len(device_infs) if inf.prepared is not None \
                 else 1
+            # co-launched batches share one dispatch: their launch spans
+            # carry the coalesced bucket count (and literally the same
+            # [t0, t1) window) so a trace shows one device dispatch per
+            # multi-bucket tick, not one per batch
+            if inf.trace is not None:
+                attrs = dict(coalesced=inf.coalesced) \
+                    if inf.coalesced > 1 else {}
+                inf.trace.span("launch", ln.t0, ln.t1, **attrs)
 
     def _complete_stage(self, inf: _Inflight) -> int:
         """COMPUTE tail: block on the device result, gather per-query
@@ -696,10 +708,34 @@ class GNNServeEngine:
         if inf.prepared is None:
             logits = inf.result
         else:
-            logits = inf.session.finish_batch(inf.prepared, inf.devs)
+            with span("finish"):
+                logits = inf.session.finish_batch(inf.prepared, inf.devs)
         t_done = time.perf_counter()
-        # serve-path counters move here — after the batch can no longer
-        # fail into the requeue/retry path — so they are retry-invariant
+        with span("complete"):
+            self._record_completion(inf, t_done)
+            with span("deliver"):
+                self._deliver(inf, logits)
+            for q in inf.batch:
+                q.t_done = t_done
+                self.metrics.queries += 1
+                self.metrics.latency.record(q.latency_s)
+                self.metrics.record_tenant_query(q.tenant, q.latency_s)
+                self.finished.append(q)
+            self.batch_log.append(list(inf.batch))
+            with self._qlock:
+                self._unanswered -= len(inf.batch)
+                if self.slo is not None:
+                    for q in inf.batch:
+                        self.slo.observe(q.tenant, t_done,
+                                         latency_s=q.latency_s)
+                    self.slo.check(t_done, self.admission)
+        return len(inf.batch)
+
+    def _record_completion(self, inf: _Inflight, t_done: float) -> None:
+        """Serve-path counters, stage times, cost attribution and the ring
+        commit of one completed batch. The counters move here, after the
+        batch can no longer fail into the requeue/retry path, so they are
+        retry-invariant."""
         if inf.prepared is None:
             self.metrics.full_cache_hits += len(inf.batch)
         else:
@@ -731,39 +767,13 @@ class GNNServeEngine:
                     pred_units=pred_units, measured_s=service_s,
                     n_pad=n_pad, units=units, attributed_s=shares)
         if inf.trace is not None:
-            t_le = inf.t_launch_end or t_done
-            # co-launched batches share one dispatch: their launch spans
-            # carry the coalesced bucket count (and literally the same
-            # [t0, t1) window) so a trace shows one device dispatch per
-            # multi-bucket tick, not one per batch
-            if inf.coalesced > 1:
-                inf.trace.span("launch", inf.t_launch, t_le,
-                               coalesced=inf.coalesced)
-            else:
-                inf.trace.span("launch", inf.t_launch, t_le)
             # the wall span launch_end -> done plus the de-overlapped time
             # this batch actually contributed (what record_stages summed)
-            inf.trace.span("compute", t_le, t_done,
+            inf.trace.span("compute", inf.t_launch_end or t_done, t_done,
                            attributed_s=compute_attr_s)
             inf.trace.t_end = t_done
             self.tracer.commit(inf.trace)
             inf.trace = None
-        self._deliver(inf, logits)
-        for q in inf.batch:
-            q.t_done = t_done
-            self.metrics.queries += 1
-            self.metrics.latency.record(q.latency_s)
-            self.metrics.record_tenant_query(q.tenant, q.latency_s)
-            self.finished.append(q)
-        self.batch_log.append(list(inf.batch))
-        with self._qlock:
-            self._unanswered -= len(inf.batch)
-            if self.slo is not None:
-                for q in inf.batch:
-                    self.slo.observe(q.tenant, t_done,
-                                     latency_s=q.latency_s)
-                self.slo.check(t_done, self.admission)
-        return len(inf.batch)
 
     def _deliver(self, inf: _Inflight, result) -> None:
         """Family-specific answer delivery: write each member query's
@@ -868,16 +878,17 @@ class GNNServeEngine:
             raise
 
     def _step(self, block: bool) -> int:
-        t0 = time.perf_counter()
-        try:
-            if self.pipeline_depth <= 0:
-                inf = self._extract_stage()
-                if inf is None:
-                    return 0
-                return self._compute(inf)
-            return self._pump(block)
-        finally:
-            self.metrics.serve_wall_s += time.perf_counter() - t0
+        with span("tick") as tick:
+            try:
+                if self.pipeline_depth <= 0:
+                    inf = self._extract_stage()
+                    if inf is None:
+                        return 0
+                    return self._compute(inf)
+                return self._pump(block)
+            finally:
+                self.metrics.serve_wall_s += \
+                    time.perf_counter() - tick.t0
 
     def tick(self) -> int:
         """Serve ONE pipeline step. Serial engine: extract + compute one
@@ -1074,10 +1085,17 @@ class GNNServeEngine:
             self.recompile_watchdog.arm()
 
     def snapshot(self) -> dict:
-        inval = sum(s.invalidations for s in self._sessions())
+        sessions = list(self._sessions())
+        inval = sum(s.invalidations for s in sessions)
+        # host-to-device bytes: feature uploads of the full-cache refresh
+        # (session.sync) and the arrays handed to each bucketed launch
+        uploaded = dict(
+            sync=sum(getattr(s, "bytes_uploaded", 0) for s in sessions),
+            launch=sum(getattr(getattr(s, "core", None), "bytes_uploaded",
+                               0) for s in sessions))
         extra = dict(
             compiles=self.compile_count, invalidations=inval,
-            dispatches=self.dispatch_count,
+            bytes_uploaded=uploaded, dispatches=self.dispatch_count,
             pending=self.pending, pipeline_depth=self.pipeline_depth,
             multi_bucket=self.multi_bucket,
             watchdogs=dict(recompile=self.recompile_watchdog.snapshot(),

@@ -52,6 +52,7 @@ from repro.kernels import ops as kernel_ops
 from repro.serve import adapters, session_core
 from repro.serve.session_core import (  # re-exported (stable import path)
     FAMILIES, FAMILY_AGG_LAYERS, ServeCore, SessionPlan, bucket_pow2)
+from repro.serve.trace import span
 
 # retained changelog entries per graph: an incremental session can catch up
 # across at most this many feature versions before falling back to a full
@@ -165,6 +166,8 @@ class CompiledGraphSession:
         self._full_cache: Optional[np.ndarray] = None
         self._invalidations = 0
         self._incremental_refreshes = 0
+        # host-to-device bytes of every feature upload in sync()
+        self.bytes_uploaded = 0
         # adj_full injected on artifact restore (skips re-encoding the graph)
         self._adj_full = (adj_full if adj_full is not None
                           else self._build_full_adjacencies())
@@ -211,21 +214,28 @@ class CompiledGraphSession:
         of the changed rows. No-op when already current."""
         if self.feature_version == self.graph.version:
             return
-        invalidated = self.feature_version >= 0
-        changed = None
-        if (self.incremental and invalidated and self.bn is not None
-                and self._full_cache is not None):
-            changed = self.graph.changed_since(self.feature_version)
-        self._x_dev = jnp.asarray(self.graph.data.x)
-        if changed is None:
-            out, bn = self._jit_full(self._x_dev)
-            self.bn = bn
-            self._full_cache = np.array(out)   # writable: patched in place
-        elif changed.size:
-            self._refresh_incremental(changed)
-        self.feature_version = self.graph.version
-        if invalidated:
-            self._invalidations += 1
+        with span("sync"):
+            invalidated = self.feature_version >= 0
+            changed = None
+            if (self.incremental and invalidated and self.bn is not None
+                    and self._full_cache is not None):
+                changed = self.graph.changed_since(self.feature_version)
+            x = self.graph.data.x
+            with span("sync.upload"):
+                self._x_dev = jnp.asarray(x)
+            self.bytes_uploaded += x.nbytes
+            if changed is None:
+                with span("sync.forward"):
+                    out, bn = self._jit_full(self._x_dev)
+                self.bn = bn
+                with span("sync.copyback"):
+                    # writable: patched in place
+                    self._full_cache = np.array(out)
+            elif changed.size:
+                self._refresh_incremental(changed)
+            self.feature_version = self.graph.version
+            if invalidated:
+                self._invalidations += 1
 
     def _refresh_incremental(self, changed: np.ndarray) -> None:
         """Patch the cached logits of every node whose output can depend on
@@ -281,11 +291,14 @@ class CompiledGraphSession:
     def _extract(self, uniq_seeds: np.ndarray):
         """Host-side k-hop extraction + subgraph FRDC build (no device work
         — also used by warmup to probe steady-state shapes cheaply)."""
-        ex = sampling.extract_khop(self.graph.csr, uniq_seeds, self.khop)
+        with span("extract.khop"):
+            ex = sampling.extract_khop(self.graph.csr, uniq_seeds,
+                                       self.khop)
         dinv = self.graph.dinv_for(self.plan.family)
-        mats = self.adapter.sub_operands(
-            ex.sub_nodes.size, ex.sub_edges,
-            None if dinv is None else dinv[ex.sub_nodes])
+        with span("extract.frdc"):
+            mats = self.adapter.sub_operands(
+                ex.sub_nodes.size, ex.sub_edges,
+                None if dinv is None else dinv[ex.sub_nodes])
         return ex.sub_nodes, mats, ex.seed_pos
 
     def prepare_batch(self, seeds: np.ndarray) -> session_core.PreparedBatch:
@@ -298,8 +311,9 @@ class CompiledGraphSession:
         seeds = np.asarray(seeds, np.int64)
         uniq, inverse = np.unique(seeds, return_inverse=True)
         sub_nodes, mats, seed_pos = self._extract(uniq)
-        staged = self.core.stage(self.graph.data.x[sub_nodes], mats,
-                                 seed_pos)
+        with span("extract.stage"):
+            staged = self.core.stage(self.graph.data.x[sub_nodes], mats,
+                                     seed_pos)
         group = session_core.PreparedGroup(
             core=self.core, sel=np.arange(uniq.size), staged=staged)
         return session_core.PreparedBatch(n_uniq=uniq.size, inverse=inverse,

@@ -660,6 +660,9 @@ class ServeCore:
         # device dispatches issued (a launch_many of K buckets counts 1) —
         # the launches-per-tick regression metric benches and tests key on
         self.n_dispatches = 0
+        # host bytes of every numpy array handed to a launch (features,
+        # seed positions, padded adjacency arrays)
+        self.bytes_uploaded = 0
 
     @property
     def compile_count(self) -> int:
@@ -706,6 +709,7 @@ class ServeCore:
         caller can overlap the next batch's extraction with it."""
         c0 = self._n_traces
         self.n_dispatches += 1
+        self.bytes_uploaded += staged.host_bytes()
         out = self._jit_serve(jnp.asarray(staged.x_pad), bn, staged.adjs,
                               jnp.asarray(staged.pos_pad))
         if self._n_traces > c0 and self.on_trace is not None:
@@ -728,6 +732,7 @@ class ServeCore:
             return [self.launch(staged, bn)]
         c0 = self._n_traces
         self.n_dispatches += 1
+        self.bytes_uploaded += sum(s.host_bytes() for s, _ in entries)
         batches = tuple(
             (jnp.asarray(s.x_pad), bn, s.adjs, jnp.asarray(s.pos_pad))
             for s, bn in entries)
@@ -780,6 +785,12 @@ class StagedBatch:
     adjs: Dict[str, dict]           # padded FRDC arrays per adjacency kind
     pos_pad: np.ndarray             # (max_batch,) seed positions, padded
     n_seeds: int
+
+    def host_bytes(self) -> int:
+        """Bytes of the host arrays a launch of this batch uploads."""
+        leaves = jax.tree_util.tree_leaves((self.x_pad, self.adjs,
+                                            self.pos_pad))
+        return sum(a.nbytes for a in leaves if isinstance(a, np.ndarray))
 
 
 @dataclasses.dataclass

@@ -32,14 +32,17 @@ Watchdogs turn two test-only invariants into runtime signals:
     and the launch stage pure async dispatch. The watchdog checks both at
     the launch seam: a device-resident staged array means extraction
     touched the device; a launch returning concrete host arrays means the
-    dispatch blocked on a device->host sync. (``strict_guard()``
-    additionally arms jax's transfer guard around a block — it raises on
-    real accelerators, and is a no-op on the CPU backend where device
-    arrays are host-local.)
+    dispatch blocked on a device->host sync.
+
+Beneath the batch spans, :func:`span` puts the serving program's own work
+on the profiler's clock: each call opens a ``jax.profiler.TraceAnnotation``
+named ``serve.<name>`` (the names are :data:`SPANS`), which records nothing
+unless a profiler is collecting, so a trace of the program lays its host
+work beside the device's. Given a :class:`BatchTrace` it also appends the
+stage's :class:`SpanEvent`, so the ring and the profiler see one span.
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import logging
 import threading
@@ -47,6 +50,7 @@ import time
 from typing import Callable, Dict, List, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 log = logging.getLogger(__name__)
 
@@ -57,6 +61,27 @@ TRACE_SCHEMA_VERSION = 1
 # span names of the serving pipeline, in stage order — the per-stage tracks
 # of the Chrome-trace export
 STAGES = ("queue_wait", "extract", "launch", "compute")
+
+# the program's spans on the profiler's clock (see :func:`span`), one per
+# batch or per sync, never per query; nesting is per thread
+SPAN_PREFIX = "serve."
+SPANS = (
+    "serve.tick",            # GNNServeEngine._step: one serve step
+    "serve.pick",            # queue pick, batch pop, admission on_served
+    "serve.extract",         # _prepare_stage: answer lookup or subgraph prep
+    "serve.lookup",          # full-cache path: session.full_logits()[seeds]
+    "serve.sync",            # CompiledGraphSession.sync, when it does work
+    "serve.sync.upload",     # the feature matrix to the device
+    "serve.sync.forward",    # dispatch of the calibrated full-graph forward
+    "serve.sync.copyback",   # block on it, logits to the host
+    "serve.extract.khop",    # k-hop closure (sampling.extract_khop)
+    "serve.extract.frdc",    # subgraph FRDC build (adapter.sub_operands)
+    "serve.extract.stage",   # bucket pad (ServeCore.stage)
+    "serve.launch",          # dispatch of the bucketed forward(s)
+    "serve.finish",          # block on it, crop the answers to the host
+    "serve.complete",        # metrics, cost, ring commit, answers, SLO
+    "serve.deliver",         # write each query's answer fields
+)
 
 # event names the replica tier emits through SpanTracer.event (always-kept
 # WarningEvent records, like the watchdog firings): replica health
@@ -82,6 +107,36 @@ class SpanEvent:
     def to_json(self) -> dict:
         return dict(name=self.name, t0=self.t0, t1=self.t1,
                     duration_s=self.duration_s, **self.attrs)
+
+
+class span:
+    """``with span(name[, trace, **attrs]) as sp:`` — one span of the
+    serving program on the profiler's clock: a ``TraceAnnotation`` named
+    ``serve.<name>`` (an entry of :data:`SPANS`), about a microsecond when
+    no profiler collects. ``sp.t0``/``sp.t1`` are its ``perf_counter``
+    bounds; when ``trace`` (a :class:`BatchTrace`) is given and the block
+    does not raise, the span is also appended to it under ``name`` with
+    ``attrs``. A class, not a generator, to keep the hot path cheap."""
+
+    __slots__ = ("name", "trace", "attrs", "t0", "t1", "_ann")
+
+    def __init__(self, name: str, trace: Optional["BatchTrace"] = None,
+                 **attrs):
+        self.name = name
+        self.trace = trace
+        self.attrs = attrs
+        self._ann = TraceAnnotation(SPAN_PREFIX + name)
+
+    def __enter__(self) -> "span":
+        self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        self.t1 = time.perf_counter()
+        self._ann.__exit__(exc_type, exc, tb)
+        if self.trace is not None and exc_type is None:
+            self.trace.span(self.name, self.t0, self.t1, **self.attrs)
 
 
 @dataclasses.dataclass
@@ -414,22 +469,6 @@ class TransferWatchdog:
                 self.host_sync_in_launch += 1
                 self._emit(self.host_sync_in_launch, "host_sync_in_launch",
                            group=i)
-
-    @contextlib.contextmanager
-    def strict_guard(self):
-        """Arm jax's device->host transfer guard for the enclosed block:
-        on real accelerators an unexpected sync RAISES (and is counted);
-        on the CPU backend device arrays are host-local and the guard never
-        fires — the isinstance checks above carry the signal there."""
-        import jax
-        try:
-            with jax.transfer_guard_device_to_host("disallow"):
-                yield
-        except Exception:
-            self.host_sync_in_launch += 1
-            self._emit(self.host_sync_in_launch, "host_sync_in_launch",
-                       source="transfer_guard")
-            raise
 
     def snapshot(self) -> dict:
         return dict(family=self.family,

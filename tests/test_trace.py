@@ -438,3 +438,172 @@ def test_tracer_disabled_is_noop(store, data):
     snap = engine.snapshot()
     assert snap["trace"]["enabled"] is False
     engine.close()
+
+
+# ------------------------------------------- spans on the profiler clock ---
+
+# the parent each serve.* span has (a sync runs under the full-cache
+# lookup, or under a subgraph batch's extract)
+SPAN_PARENT = {
+    "serve.tick": (None,),
+    "serve.pick": ("serve.tick",),
+    "serve.extract": ("serve.tick",),
+    "serve.lookup": ("serve.extract",),
+    "serve.sync": ("serve.lookup", "serve.extract"),
+    "serve.sync.upload": ("serve.sync",),
+    "serve.sync.forward": ("serve.sync",),
+    "serve.sync.copyback": ("serve.sync",),
+    "serve.extract.khop": ("serve.extract",),
+    "serve.extract.frdc": ("serve.extract",),
+    "serve.extract.stage": ("serve.extract",),
+    "serve.launch": ("serve.tick",),
+    "serve.finish": ("serve.tick",),
+    "serve.complete": ("serve.tick",),
+    "serve.deliver": ("serve.complete",),
+}
+# what each serve path runs in a tick that follows a feature update
+PATH_SPANS = {
+    "full": set(SPAN_PARENT) - {"serve.finish", "serve.extract.khop",
+                                "serve.extract.frdc",
+                                "serve.extract.stage"},
+    "subgraph": set(SPAN_PARENT) - {"serve.lookup"},
+}
+
+
+@pytest.fixture()
+def own_store():
+    """A store over a graph of its own: these tests update features."""
+    d = make_dataset("cora", seed=1, scale=0.1)
+    st = GraphStore(max_batch=BATCH)
+    st.register_graph("g", d)
+    st.register_model("gcn", "gcn",
+                      gnn.init_gcn(jax.random.PRNGKey(0), d.x.shape[1],
+                                   HIDDEN, d.n_classes))
+    return st
+
+
+def test_spans_table_is_the_program_list():
+    from repro.serve.trace import SPANS
+    assert set(SPANS) == set(SPAN_PARENT)
+    assert len(SPANS) == len(set(SPANS))
+
+
+@pytest.mark.parametrize("mode", ["full", "subgraph"])
+def test_program_spans_nest_on_profiler_clock(own_store, mode, tmp_path):
+    """Under a profiler, one feature update and a few batches leave every
+    span of the path in the trace, each under the parent it belongs to,
+    and no serve.* name outside ``SPANS``."""
+    from jax.profiler import ProfileData
+    from chipbench import spans
+    from repro.serve.trace import SPANS
+
+    engine = GNNServeEngine(own_store, max_batch=BATCH, mode=mode,
+                            tracer=SpanTracer(sample_every=1))
+    engine.warmup("g", "gcn")
+    x = own_store.graphs["g"].data.x
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        own_store.update_features("g", x + 1.0)
+        qs = engine.submit_many("g", "gcn", np.arange(3 * BATCH))
+        engine.run_until_drained()
+    finally:
+        jax.profiler.stop_trace()
+    assert all(q.done for q in qs)
+    (path,) = sorted(tmp_path.rglob("*.xplane.pb"))
+    got = spans.from_profile(ProfileData.from_file(str(path)))
+    names = {s.name for s in got}
+    assert names <= set(SPANS), names - set(SPANS)
+    assert names == PATH_SPANS[mode]
+    for s, p in zip(got, spans.parents(got)):
+        parent = None if p is None else got[p].name
+        assert parent in SPAN_PARENT[s.name], (s.name, parent)
+    # one sync, and the ring still holds its stages
+    assert sum(s.name == "serve.sync" for s in got) == 1
+    assert {e.name for t in engine.tracer.batch_traces()
+            for e in t.spans} == set(STAGES)
+    engine.close()
+
+
+@pytest.mark.parametrize("incremental", [False, True])
+def test_sync_counts_feature_upload_bytes(incremental):
+    d = make_dataset("cora", seed=2, scale=0.1)
+    st = GraphStore(max_batch=BATCH, incremental=incremental)
+    st.register_graph("g", d)
+    st.register_model("gcn", "gcn",
+                      gnn.init_gcn(jax.random.PRNGKey(0), d.x.shape[1],
+                                   HIDDEN, d.n_classes))
+    session = st.session("g", "gcn")
+    b0 = session.bytes_uploaded
+    assert b0 == d.x.nbytes                 # the build's first sync
+    session.sync()                          # current: no upload
+    assert session.bytes_uploaded == b0
+    for k in (1, 2):
+        x = d.x.copy()
+        x[:k] += 1.0
+        st.update_features("g", x)
+        session.sync()
+        assert session.bytes_uploaded == b0 + k * x.nbytes
+    engine = GNNServeEngine(st, max_batch=BATCH, mode="full")
+    engine.submit("g", "gcn", 0)
+    engine.run_until_drained()
+    assert engine.snapshot()["bytes_uploaded"]["sync"] == 3 * d.x.nbytes
+
+
+def test_launch_counts_host_bytes(store, data):
+    """ServeCore.bytes_uploaded rises per launch by the bytes of every host
+    array handed to the jitted forward, for one bucket and a co-launch."""
+    session = store.session("g", "gcn")
+    core = session.core
+    rng = np.random.default_rng(5)
+    preps = [session.prepare_batch(rng.integers(0, data.n_nodes, BATCH))
+             for _ in range(2)]
+
+    def host_bytes(staged):
+        adj = sum(a.nbytes for m in staged.adjs.values()
+                  for a in m.values() if isinstance(a, np.ndarray))
+        return staged.x_pad.nbytes + staged.pos_pad.nbytes + adj
+
+    sizes = [host_bytes(p.groups[0].staged) for p in preps]
+    assert sizes[0] > preps[0].groups[0].staged.x_pad.nbytes
+    b0 = core.bytes_uploaded
+    devs = session.launch_batch(preps[0])
+    session.finish_batch(preps[0], devs)
+    assert core.bytes_uploaded == b0 + sizes[0]
+    outs = core.launch_many([(p.groups[0].staged, p.bn) for p in preps])
+    jax.block_until_ready(outs)
+    assert core.bytes_uploaded == b0 + 2 * sizes[0] + sizes[1]
+
+
+def test_forward_program_names_match_the_benchmark():
+    """The benchmark finds the forwards in a device trace by the names XLA
+    gives their programs (``chipbench/record.py``); a rename of the jitted
+    functions has to fail here, not silently empty its device metrics."""
+    import re
+    import sys
+    from pathlib import Path
+    root = str(Path(__file__).resolve().parents[1])
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from chipbench.record import FULL_FORWARD, SERVE_FORWARD
+
+    d = make_dataset("cora", seed=3, scale=0.1)
+    st = GraphStore(max_batch=BATCH)
+    st.register_graph("g", d)
+    st.register_model("gcn", "gcn",
+                      gnn.init_gcn(jax.random.PRNGKey(0), d.x.shape[1],
+                                   HIDDEN, d.n_classes))
+    session = st.session("g", "gcn")
+    x = jax.numpy.asarray(d.x)
+    s = session.prepare_batch(np.arange(BATCH)).groups[0].staged
+    args = (jax.numpy.asarray(s.x_pad), session.bn, s.adjs,
+            jax.numpy.asarray(s.pos_pad))
+    core = session.core
+    lowered = {
+        FULL_FORWARD: [session._jit_full.lower(x),
+                       session._jit_full_frozen.lower(x, session.bn)],
+        SERVE_FORWARD: [core._jit_serve.lower(*args),
+                        core._jit_serve_many.lower((args, args))]}
+    for pattern, lows in lowered.items():
+        for low in lows:
+            name = re.match(r"module @(\S+)", low.as_text()).group(1)
+            assert re.search(pattern, name), (pattern, name)
