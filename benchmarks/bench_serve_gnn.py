@@ -178,10 +178,9 @@ def _kernel_path_stats(store: GraphStore, family: str,
     fused/unfused bitwise identity."""
     import time
 
-    import jax.numpy as jnp
-
     from repro.kernels import fused_layer
     from repro.kernels import ops as kernel_ops
+    from repro.serve import session_core
 
     sess = store.session("bench", family)
     fused_layer.reset_counters()
@@ -189,9 +188,10 @@ def _kernel_path_stats(store: GraphStore, family: str,
     fused_calls = fused_layer.KERNEL_CALLS["fused"]
     prepared = sess.prepare_batch(np.asarray(seeds, np.int64))
     g = prepared.groups[0]
-    tr = kernel_ops.launch_stats(
-        g.core._serve_one, jnp.asarray(g.staged.x_pad), prepared.bn,
-        g.staged.adjs, jnp.asarray(g.staged.pos_pad))
+    x, *args = g.staged.launch_args(prepared.bn)
+    # count the layers' operations: the input-row gather is not a layer's
+    tr = kernel_ops.launch_stats(g.core._serve_one,
+                                 session_core.forward_input(x), *args)
     best = float("inf")
     for _ in range(repeats):
         t0 = time.perf_counter()

@@ -223,12 +223,10 @@ def cpu_agreement(sess, data) -> None:
 
 def hlo_has_kernel(sess, nodes) -> None:
     """The compiled subgraph serve executable runs the Pallas kernels."""
-    import jax.numpy as jnp
     prepared = sess.prepare_batch(nodes[:BATCH])
-    staged = prepared.groups[0].staged
     text = sess.core._jit_serve.lower(
-        jnp.asarray(staged.x_pad), prepared.bn, staged.adjs,
-        jnp.asarray(staged.pos_pad)).compile().as_text()
+        *prepared.groups[0].staged.launch_args(prepared.bn)
+    ).compile().as_text()
     check("tpu_custom_call" in text,
           "pallas serve executable contains tpu_custom_call")
 

@@ -153,7 +153,7 @@ class GNNAdapter(ModelFamilyAdapter):
 
     def trace_shape(self, staged) -> dict:
         return dict(
-            n_pad=int(staged.x_pad.shape[0]),
+            n_pad=staged.n_pad,
             groups={str(k): int(a["group_row"].shape[0])
                     for k, a in staged.adjs.items()})
 

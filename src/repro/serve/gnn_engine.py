@@ -6,8 +6,10 @@ The serving hot path is an explicit two-stage pipeline:
 
   * **extract** — queue pick (incremental oldest-head heap) -> batch
     formation -> deterministic k-hop extraction around the batch's seeds ->
-    subgraph FRDC build -> shape-bucket padding. Pure host work: producing a
-    :class:`~repro.serve.session_core.PreparedBatch` touches no device.
+    subgraph FRDC build -> shape-bucket padding. Host work only: producing
+    a :class:`~repro.serve.session_core.PreparedBatch` computes nothing on
+    the device (a single-host batch carries row ids and captures the
+    session's resident feature matrix; the launch gathers the rows).
   * **compute** — launch the jitted bucketed forward (async under jax
     dispatch), block on the result, gather per-query logits.
 
@@ -537,7 +539,7 @@ class GNNServeEngine:
         if prepared is None:
             return {}
         return dict(groups=[
-            dict(n_pad=int(g.staged.x_pad.shape[0]),
+            dict(n_pad=g.staged.n_pad,
                  g_pad={str(k): int(a["group_row"].shape[0])
                         for k, a in g.staged.adjs.items()})
             for g in prepared.groups])
@@ -756,8 +758,8 @@ class GNNServeEngine:
             service_s = inf.extract_s + compute_attr_s
             n_pad = 0
             if inf.prepared is not None:
-                n_pad = max((int(g.staged.x_pad.shape[0])
-                             for g in inf.prepared.groups), default=0)
+                n_pad = max((g.staged.n_pad for g in inf.prepared.groups),
+                            default=0)
             self.cost.observe_batch(pred_units, service_s, n_pad=n_pad)
             shares = self.cost.attribute(units, service_s)
             for q, share in zip(inf.batch, shares):
@@ -1088,14 +1090,18 @@ class GNNServeEngine:
         sessions = list(self._sessions())
         inval = sum(s.invalidations for s in sessions)
         # host-to-device bytes: feature uploads of the full-cache refresh
-        # (session.sync) and the arrays handed to each bucketed launch
+        # (session.sync) and the host arrays handed to each bucketed launch
+        cores = [c for s in sessions
+                 for c in (getattr(s, "cores", None)
+                           or [getattr(s, "core", None)]) if c is not None]
         uploaded = dict(
             sync=sum(getattr(s, "bytes_uploaded", 0) for s in sessions),
-            launch=sum(getattr(getattr(s, "core", None), "bytes_uploaded",
-                               0) for s in sessions))
+            launch=sum(c.bytes_uploaded for c in cores))
         extra = dict(
             compiles=self.compile_count, invalidations=inval,
-            bytes_uploaded=uploaded, dispatches=self.dispatch_count,
+            bytes_uploaded=uploaded,
+            resident_launches=sum(c.resident_launches for c in cores),
+            dispatches=self.dispatch_count,
             pending=self.pending, pipeline_depth=self.pipeline_depth,
             multi_bucket=self.multi_bucket,
             watchdogs=dict(recompile=self.recompile_watchdog.snapshot(),

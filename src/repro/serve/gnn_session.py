@@ -303,16 +303,19 @@ class CompiledGraphSession:
 
     def prepare_batch(self, seeds: np.ndarray) -> session_core.PreparedBatch:
         """EXTRACT stage: adopt current features, k-hop extract, build the
-        subgraph FRDC and bucket-pad — pure host work producing the
-        launch-ready :class:`~repro.serve.session_core.PreparedBatch` (the
-        pipelined engine runs this on a background worker while the previous
-        batch's forward is in flight)."""
+        subgraph FRDC and bucket-pad the closure's row ids — host work
+        producing the launch-ready
+        :class:`~repro.serve.session_core.PreparedBatch` (the pipelined
+        engine runs this on a background worker while the previous batch's
+        forward is in flight). The features stay where ``sync`` put them:
+        the batch captures the session's resident device matrix, and the
+        launch gathers the closure's rows from it on the device."""
         self.sync()
         seeds = np.asarray(seeds, np.int64)
         uniq, inverse = np.unique(seeds, return_inverse=True)
         sub_nodes, mats, seed_pos = self._extract(uniq)
         with span("extract.stage"):
-            staged = self.core.stage(self.graph.data.x[sub_nodes], mats,
+            staged = self.core.stage((self._x_dev, sub_nodes), mats,
                                      seed_pos)
         group = session_core.PreparedGroup(
             core=self.core, sel=np.arange(uniq.size), staged=staged)
@@ -332,7 +335,7 @@ class CompiledGraphSession:
         """One extraction + bucketed forward for <= max_batch unique seeds,
         against the CURRENT features and frozen calibration (no sync)."""
         sub_nodes, mats, seed_pos = self._extract(uniq_seeds)
-        return self.core.run(self.graph.data.x[sub_nodes], mats, seed_pos,
+        return self.core.run((self._x_dev, sub_nodes), mats, seed_pos,
                              self.bn)
 
     def serve_subgraph(self, seeds: np.ndarray) -> np.ndarray:

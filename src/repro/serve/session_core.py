@@ -611,6 +611,16 @@ class LayerExecutor:
 # ServeCore — the bucket-shaped jitted subgraph forward
 # ---------------------------------------------------------------------------
 
+def forward_input(x):
+    """The rows a staged forward runs on: ``x`` itself, or, for a
+    ``(resident, rows)`` pair, the rows gathered from the resident matrix
+    on the device, where an id past its last row reads as a zero row."""
+    if not isinstance(x, tuple):
+        return x
+    resident, rows = x
+    return jnp.take(resident, rows, axis=0, mode="fill", fill_value=0)
+
+
 class ServeCore:
     """One jitted bucketed forward + its high-water shape buckets.
 
@@ -660,9 +670,13 @@ class ServeCore:
         # device dispatches issued (a launch_many of K buckets counts 1) —
         # the launches-per-tick regression metric benches and tests key on
         self.n_dispatches = 0
-        # host bytes of every numpy array handed to a launch (features,
-        # seed positions, padded adjacency arrays)
+        # host bytes of every numpy array handed to a launch (features or
+        # row ids, seed positions, padded adjacency arrays); features
+        # gathered from a resident matrix are not among them
         self.bytes_uploaded = 0
+        # launched batches whose features were gathered on the device from
+        # a resident matrix (a co-launch counts each of its batches)
+        self.resident_launches = 0
 
     @property
     def compile_count(self) -> int:
@@ -673,7 +687,8 @@ class ServeCore:
         return self._serve_one(x, bn, adjs, seeds)
 
     def _serve_one(self, x, bn, adjs, seeds):
-        return self.adapter.serve_body(self, x, bn, adjs, seeds)
+        return self.adapter.serve_body(self, forward_input(x), bn, adjs,
+                                       seeds)
 
     def _serve_many(self, batches):
         """K bucketed forwards UNROLLED into one jitted program (one device
@@ -689,19 +704,36 @@ class ServeCore:
     def _pad_mats(self, mats: Dict[str, frdc.FRDCMatrix], n_sub: int):
         return self.adapter.pad_operands(self, mats, n_sub)
 
-    def stage(self, x_sub: np.ndarray, mats: Dict[str, frdc.FRDCMatrix],
+    def stage(self, x_sub, mats: Dict[str, frdc.FRDCMatrix],
               seed_pos: np.ndarray) -> "StagedBatch":
         """EXTRACT-stage tail: bucket-pad one extracted subgraph into the
-        launch-ready host arrays. Pure host work (the water-mark update
+        launch-ready host arrays. Host work only (the water-mark update
         happens here, so staging order — not launch order — is what the
-        zero-recompile guarantee keys on)."""
-        n_pad, adjs = self._pad_mats(mats, x_sub.shape[0])
-        x_pad = np.zeros((n_pad, x_sub.shape[1]), np.float32)
-        x_pad[:x_sub.shape[0]] = x_sub
+        zero-recompile guarantee keys on).
+
+        ``x_sub`` is either the subgraph's (n_sub, F) host features, padded
+        here with zero rows, or a ``(resident, rows)`` pair: a device matrix
+        that already holds every node's features and the subgraph's row ids
+        into it. Then only the ids are padded, with ``resident.shape[0]``,
+        which the launch's gather reads as a zero row. The matrix is
+        captured as it is, not computed: a session replaces it on a feature
+        upload and never writes into it, so this batch launches on the
+        features it was staged under."""
+        resident = rows = x_pad = None
+        if isinstance(x_sub, tuple):
+            resident, ids = x_sub
+            n_pad, adjs = self._pad_mats(mats, len(ids))
+            rows = np.full((n_pad,), resident.shape[0], np.int32)
+            rows[:len(ids)] = ids
+        else:
+            n_pad, adjs = self._pad_mats(mats, x_sub.shape[0])
+            x_pad = np.zeros((n_pad, x_sub.shape[1]), np.float32)
+            x_pad[:x_sub.shape[0]] = x_sub
         pos_pad = np.zeros((self.max_batch,), np.int32)
         pos_pad[:seed_pos.size] = seed_pos
         return StagedBatch(x_pad=x_pad, adjs=adjs, pos_pad=pos_pad,
-                           n_seeds=int(seed_pos.size))
+                           n_seeds=int(seed_pos.size), rows=rows,
+                           resident=resident)
 
     def launch(self, staged: "StagedBatch", bn: tuple) -> jax.Array:
         """COMPUTE-stage head: dispatch the jitted bucketed forward. Under
@@ -710,8 +742,8 @@ class ServeCore:
         c0 = self._n_traces
         self.n_dispatches += 1
         self.bytes_uploaded += staged.host_bytes()
-        out = self._jit_serve(jnp.asarray(staged.x_pad), bn, staged.adjs,
-                              jnp.asarray(staged.pos_pad))
+        self.resident_launches += staged.resident is not None
+        out = self._jit_serve(*staged.launch_args(bn))
         if self._n_traces > c0 and self.on_trace is not None:
             # a NEW trace: report the offending shape key (the padded dims
             # that define the jit cache entry)
@@ -733,10 +765,10 @@ class ServeCore:
         c0 = self._n_traces
         self.n_dispatches += 1
         self.bytes_uploaded += sum(s.host_bytes() for s, _ in entries)
-        batches = tuple(
-            (jnp.asarray(s.x_pad), bn, s.adjs, jnp.asarray(s.pos_pad))
-            for s, bn in entries)
-        outs = self._jit_serve_many(batches)
+        self.resident_launches += sum(s.resident is not None
+                                      for s, _ in entries)
+        outs = self._jit_serve_many(
+            tuple(s.launch_args(bn) for s, bn in entries))
         if self._n_traces > c0 and self.on_trace is not None:
             self.on_trace(self.adapter.trace_shape_many(
                 [s for s, _ in entries]))
@@ -747,11 +779,12 @@ class ServeCore:
         to host answers (GNN: the seed rows)."""
         return self.adapter.finish(out_dev, staged)
 
-    def run(self, x_sub: np.ndarray, mats: Dict[str, frdc.FRDCMatrix],
+    def run(self, x_sub, mats: Dict[str, frdc.FRDCMatrix],
             seed_pos: np.ndarray, bn: tuple) -> np.ndarray:
         """Serial stage -> launch -> finish of one extracted subgraph.
 
-        ``x_sub``: (n_sub, F) features of the subgraph nodes (global order);
+        ``x_sub``: (n_sub, F) features of the subgraph nodes (global order),
+        or a ``(resident, rows)`` pair as :meth:`stage` takes it;
         ``seed_pos``: positions of the seeds inside the subgraph. Returns
         (len(seed_pos), n_out) logits. The pipelined engine calls the three
         stages itself; composing them here keeps serial and pipelined
@@ -780,15 +813,41 @@ class ServeCore:
 
 @dataclasses.dataclass
 class StagedBatch:
-    """One bucket-padded subgraph, ready for :meth:`ServeCore.launch`."""
-    x_pad: np.ndarray               # (n_pad, F) zero-padded features
+    """One bucket-padded subgraph, ready for :meth:`ServeCore.launch`.
+
+    Its rows come one of two ways: ``x_pad``, the features padded with zero
+    rows, or ``rows``, padded ids into ``resident``, a device matrix of
+    every node's features that the session already held when the batch was
+    staged; the launch then gathers the rows on the device. Extract
+    produces host arrays only: ``resident`` is captured, not computed."""
+    x_pad: Optional[np.ndarray]     # (n_pad, F) zero-padded features
     adjs: Dict[str, dict]           # padded FRDC arrays per adjacency kind
     pos_pad: np.ndarray             # (max_batch,) seed positions, padded
     n_seeds: int
+    rows: Optional[np.ndarray] = None        # (n_pad,) int32 ids into resident
+    resident: Optional[jax.Array] = None     # (n_nodes, F) on the device
+
+    @property
+    def row_operand(self) -> np.ndarray:
+        """What extract produced for the batch's rows: the padded ids when
+        they are gathered from ``resident``, else the padded features."""
+        return self.x_pad if self.rows is None else self.rows
+
+    @property
+    def n_pad(self) -> int:
+        """The padded node bucket (rows of the forward's input)."""
+        return int(self.row_operand.shape[0])
+
+    def launch_args(self, bn) -> tuple:
+        """Arguments ``(x, bn, adjs, seeds)`` of the jitted forward; ``x`` is
+        the ``(resident, rows)`` pair when the rows are gathered there."""
+        x = (jnp.asarray(self.x_pad) if self.resident is None
+             else (self.resident, jnp.asarray(self.rows)))
+        return x, bn, self.adjs, jnp.asarray(self.pos_pad)
 
     def host_bytes(self) -> int:
         """Bytes of the host arrays a launch of this batch uploads."""
-        leaves = jax.tree_util.tree_leaves((self.x_pad, self.adjs,
+        leaves = jax.tree_util.tree_leaves((self.x_pad, self.rows, self.adjs,
                                             self.pos_pad))
         return sum(a.nbytes for a in leaves if isinstance(a, np.ndarray))
 
@@ -815,7 +874,8 @@ class PreparedBatch:
     EXTRACT TIME: a pipelined engine may see a feature update (and
     recalibration) land between staging batch i and launching it, and the
     launch must use the constants the features were staged under — never
-    the session's live ``bn``."""
+    the session's live ``bn``. A group's ``staged.resident`` feature matrix
+    is captured at the same point, for the same reason."""
     n_uniq: int
     inverse: np.ndarray
     groups: List[PreparedGroup]

@@ -454,9 +454,12 @@ class TransferWatchdog:
                                 kind=kind, **attrs)
 
     def check_prepared(self, prepared) -> None:
-        """EXTRACT-purity check on a PreparedBatch about to launch."""
+        """EXTRACT-purity check on a PreparedBatch about to launch: what
+        extract produced for each group's rows (padded features or row ids)
+        must be a host array. A resident feature matrix the batch captured
+        is not extract's output and is not checked."""
         for i, g in enumerate(getattr(prepared, "groups", ()) or ()):
-            x = g.staged.x_pad
+            x = g.staged.row_operand
             if not isinstance(x, np.ndarray):
                 self.device_in_extract += 1
                 self._emit(self.device_in_extract, "device_in_extract",

@@ -91,9 +91,9 @@ def test_gnn_bucket_water_monotone(gnn_store):
     sess = gnn_store.session("g", "gcn")
     rng = np.random.default_rng(0)
     big = sess.prepare_batch(rng.integers(0, 100, size=4))
-    n_big = big.groups[0].staged.x_pad.shape[0]
+    n_big = big.groups[0].staged.n_pad
     small = sess.prepare_batch(rng.integers(0, 100, size=1))
-    assert small.groups[0].staged.x_pad.shape[0] == n_big
+    assert small.groups[0].staged.n_pad == n_big
     assert n_big == 2 ** int(np.log2(n_big))
 
 

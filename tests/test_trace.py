@@ -22,6 +22,7 @@ from repro.models import gnn
 from repro.serve import (GNNServeEngine, GraphStore, LatencyStats,
                          ServeMetrics, ShardedServeEngine, SpanTracer,
                          chrome_trace, prometheus_text, write_chrome_trace)
+from repro.serve.session_core import StagedBatch
 from repro.serve.trace import STAGES, BatchTrace, TransferWatchdog
 
 jax.config.update("jax_platform_name", "cpu")
@@ -409,7 +410,9 @@ def test_engine_two_wave_qps_not_inflated(store, data):
 def test_transfer_watchdog_unit():
     class G:
         def __init__(self, x):
-            self.staged = type("S", (), {"x_pad": x})()
+            self.staged = StagedBatch(x_pad=x, adjs={},
+                                      pos_pad=np.zeros(1, np.int32),
+                                      n_seeds=1)
 
     class P:
         def __init__(self, xs):
@@ -551,7 +554,9 @@ def test_sync_counts_feature_upload_bytes(incremental):
 
 def test_launch_counts_host_bytes(store, data):
     """ServeCore.bytes_uploaded rises per launch by the bytes of every host
-    array handed to the jitted forward, for one bucket and a co-launch."""
+    array handed to the jitted forward, for one bucket and a co-launch. A
+    single-host batch hands over row ids, not features: the features it
+    gathers stay on the device and are not counted."""
     session = store.session("g", "gcn")
     core = session.core
     rng = np.random.default_rng(5)
@@ -561,10 +566,13 @@ def test_launch_counts_host_bytes(store, data):
     def host_bytes(staged):
         adj = sum(a.nbytes for m in staged.adjs.values()
                   for a in m.values() if isinstance(a, np.ndarray))
-        return staged.x_pad.nbytes + staged.pos_pad.nbytes + adj
+        return staged.rows.nbytes + staged.pos_pad.nbytes + adj
 
     sizes = [host_bytes(p.groups[0].staged) for p in preps]
-    assert sizes[0] > preps[0].groups[0].staged.x_pad.nbytes
+    s0 = preps[0].groups[0].staged
+    assert s0.x_pad is None and s0.rows.shape == (s0.n_pad,)
+    assert sizes[0] > s0.rows.nbytes
+    assert sizes[0] < s0.n_pad * data.x.shape[1] * data.x.itemsize
     b0 = core.bytes_uploaded
     devs = session.launch_batch(preps[0])
     session.finish_batch(preps[0], devs)
@@ -595,8 +603,7 @@ def test_forward_program_names_match_the_benchmark():
     session = st.session("g", "gcn")
     x = jax.numpy.asarray(d.x)
     s = session.prepare_batch(np.arange(BATCH)).groups[0].staged
-    args = (jax.numpy.asarray(s.x_pad), session.bn, s.adjs,
-            jax.numpy.asarray(s.pos_pad))
+    args = s.launch_args(session.bn)
     core = session.core
     lowered = {
         FULL_FORWARD: [session._jit_full.lower(x),
