@@ -41,6 +41,13 @@ TRINARY_DEFAULT = "s3_two_popc"
 _FP_BACKEND: Optional[Callable] = None
 _BITS_BACKEND: Optional[Callable] = None
 
+# The fp aggregation gathers GROUP_COLS rows of x per group; it runs over
+# blocks of groups whose gather holds at most this many bytes, so its
+# temporaries stay bounded whatever the graph. A block of 4 GiB holds the
+# 131,072 groups of a width-256 subgraph bucket whole.
+FP_BLOCK_BYTES = 4 << 30
+LANES = 128             # a TPU vector row: narrower widths are padded to it
+
 
 @contextlib.contextmanager
 def override_backends(fp: Optional[Callable] = None,
@@ -55,6 +62,21 @@ def override_backends(fp: Optional[Callable] = None,
         yield
     finally:
         _FP_BACKEND, _BITS_BACKEND = prev
+
+
+def fp_block_groups(width: int, dtype) -> int:
+    """Groups per block of the fp aggregation: the largest count whose
+    gather, GROUP_COLS rows of ``width`` features per group laid out in
+    LANES-wide rows, fits FP_BLOCK_BYTES."""
+    lanes = -(-width // LANES) * LANES
+    row = GROUP_COLS * lanes * jnp.dtype(dtype).itemsize
+    return max(FP_BLOCK_BYTES // row, 1)
+
+
+def fp_blocks(adj: FRDCMatrix, width: int, dtype=jnp.float32) -> int:
+    """Group blocks the fp aggregation of ``adj`` runs over ``width``
+    features."""
+    return -(-adj.n_groups // fp_block_groups(width, dtype))
 
 
 def _pad_rows(x: jax.Array, multiple: int) -> jax.Array:
@@ -78,24 +100,61 @@ def _segment_rows(contrib: jax.Array, adj: FRDCMatrix) -> jax.Array:
     return out[:adj.n_rows]
 
 
+def _group_contrib(tiles: jax.Array, col_idx: jax.Array,
+                   xp: jax.Array) -> jax.Array:
+    """(G, TILE, F) products of G groups: each group's (TILE, GROUP_COLS)
+    lane mask times its GROUP_COLS gathered rows of ``xp``."""
+    nbr = group_neighbor_ids(col_idx)              # (G, 32)
+    xg = xp[nbr]                                   # (G, 32, F)
+    mask = _mask_from_words(coarsen_groups(tiles)).astype(xp.dtype)
+    # HIGHEST: a TPU's default precision would round xg to bf16; the {0,1}
+    # mask product is exact in f32, as in the Pallas kernel
+    return jnp.einsum("gkn,gnf->gkf", mask, xg,
+                      precision=jax.lax.Precision.HIGHEST)
+
+
+def _blocked_rows(adj: FRDCMatrix, xp: jax.Array, block: int,
+                  n_blocks: int) -> jax.Array:
+    """(n_rows, F) sums of every group's contribution, one block of
+    ``block`` groups per scan step into one (n_tile_rows, TILE, F)
+    accumulator. The last block is padded with empty groups (zero tiles on
+    tile-row 0), which add nothing."""
+    pad = n_blocks * block - adj.n_groups
+
+    def blocks(a):
+        a = jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1))
+        return a.reshape(n_blocks, block, *a.shape[1:])
+
+    def step(acc, blk):
+        tiles, col_idx, group_row = blk
+        return acc.at[group_row].add(_group_contrib(tiles, col_idx, xp)), None
+
+    f = xp.shape[-1]
+    acc = jnp.zeros((adj.n_tile_rows, TILE, f), xp.dtype)
+    acc, _ = jax.lax.scan(step, acc, (blocks(adj.tiles),
+                                      blocks(adj.col_idx),
+                                      blocks(adj.group_row)))
+    return acc.reshape(adj.n_tile_rows * TILE, f)[:adj.n_rows]
+
+
 def _spmm_fp(adj: FRDCMatrix, x: jax.Array) -> jax.Array:
     """Exact Adj_eff @ X for fp X: gather + masked small-matmul per group.
 
     The (TILE, GROUP_COLS) x (GROUP_COLS, F) per-group product is the op the
     TPU kernel runs on the MXU (§3.3 'other variants': FB? loads fp rows
-    directly, no bit-transpose needed).
+    directly, no bit-transpose needed). Groups run in blocks of
+    :func:`fp_block_groups`; a matrix that fits one block is one gather and
+    one segment-sum, with no loop.
     """
     if adj.col_scale is not None:
         x = x * adj.col_scale[:, None].astype(x.dtype)
     xp = _pad_rows(x, TILE)
-    nbr = group_neighbor_ids(adj.col_idx)          # (G, 32)
-    xg = xp[nbr]                                   # (G, 32, F)
-    mask = _mask_from_words(coarsen_groups(adj.tiles)).astype(x.dtype)
-    # HIGHEST: a TPU's default precision would round xg to bf16; the {0,1}
-    # mask product is exact in f32, as in the Pallas kernel
-    contrib = jnp.einsum("gkn,gnf->gkf", mask, xg,
-                         precision=jax.lax.Precision.HIGHEST)
-    out = _segment_rows(contrib, adj)
+    n_blocks = fp_blocks(adj, x.shape[-1], x.dtype)
+    if n_blocks == 1:
+        out = _segment_rows(_group_contrib(adj.tiles, adj.col_idx, xp), adj)
+    else:
+        out = _blocked_rows(adj, xp, fp_block_groups(x.shape[-1], x.dtype),
+                            n_blocks)
     if adj.row_scale is not None:
         out = out * adj.row_scale[:, None].astype(out.dtype)
     return out

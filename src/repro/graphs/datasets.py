@@ -58,6 +58,14 @@ DATASET_STATS: Dict[str, dict] = {
 }
 
 
+def unique_edges(edges: np.ndarray, n: int) -> np.ndarray:
+    """The distinct columns of a (2, E) edge list over ``n`` nodes, sorted
+    by (row, column): ``np.unique(edges, axis=1)``, computed on one int64
+    key per edge, which sorts far faster than the column view."""
+    key = np.unique(edges[0].astype(np.int64) * n + edges[1])
+    return np.stack(np.divmod(key, n))
+
+
 def make_dataset(name: str, seed: int = 0, scale: float = 1.0,
                  homophily: float = 0.85, feature_signal: float = 0.08,
                  ) -> GraphData:
@@ -96,7 +104,7 @@ def make_dataset(name: str, seed: int = 0, scale: float = 1.0,
     keep = src != dst
     src, dst = src[keep], dst[keep]
     edges = np.concatenate([np.stack([src, dst]), np.stack([dst, src])], axis=1)
-    edges = np.unique(edges, axis=1)
+    edges = unique_edges(edges, n)
 
     # class-correlated sparse binary features (bag-of-words style)
     words_per_class = max(f // c, 1)

@@ -45,7 +45,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint.checkpointer import Checkpointer
-from repro.core import frdc
+from repro.core import bspmm, frdc
 from repro.graphs import sampling
 from repro.graphs.datasets import GraphData
 from repro.kernels import ops as kernel_ops
@@ -273,6 +273,24 @@ class CompiledGraphSession:
     def dispatch_count(self) -> int:
         """Device dispatches issued (a multi-bucket co-launch counts 1)."""
         return self.core.n_dispatches
+
+    @property
+    def aggregation_blocks(self) -> Tuple[int, ...]:
+        """Group blocks each fp aggregation of the full forward runs on the
+        jnp path, in forward order (:func:`bspmm.fp_blocks`); empty where
+        the Pallas kernels aggregate."""
+        if kernel_ops.kernels_active(self.use_pallas):
+            return ()
+        q, fam = self.qparams, self.plan.family
+        if fam == "gcn":
+            key, ws = "adj", ((q.w1, q.w2) if self.plan.scheme == "full"
+                              else (q.w2,))
+        else:
+            key, ws = ("mean" if fam == "sage" else "sum"), (q.w1_agg,
+                                                              q.w2_agg)
+        # a BinTensor holds W.T: its first dimension is the output width
+        return tuple(bspmm.fp_blocks(self._adj_full[key], w.shape[0])
+                     for w in ws)
 
     def set_trace_hook(self, cb) -> None:
         """Wire an observability callback ``cb(label, shape_dict)`` to fire

@@ -121,3 +121,57 @@ def test_gcn_serving_forward_compiles_for_v5e(one_chip, use_pallas,
     assert ("tpu_custom_call" in compiled.as_text()) == use_pallas
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+def _forward_compile(one_chip, family, n, f_in, hidden, classes, groups,
+                     kind, row_scale):
+    """Compile ``family``'s full-graph serving forward (BN calibration
+    pass, jnp path) from shapes alone: ``n`` nodes of ``f_in`` features
+    over one FRDC adjacency ``kind`` of ``groups`` groups."""
+    init = getattr(gnn, f"init_{family}")
+    q = session_core.quantize_family(
+        family, init(jax.random.PRNGKey(0), f_in, hidden, classes))
+    plan = session_core.default_plan(family)
+    bits = [t.n for t in q]
+    arrs = dict(zip(("tiles", "col_idx", "group_row", "group_first",
+                     "grp_ptr"), _frdc_shapes(one_chip, n, groups)))
+    if row_scale:
+        arrs["row_scale"] = _sds(one_chip, (n,), jnp.float32)
+
+    def fwd(x, ar, qp):
+        mats = {kind: session_core.frdc_rebuild(ar, n, n)}
+        qq = type(q)(*(BinTensor(packed=p, scale=s, n=b)
+                       for (p, s), b in zip(qp, bits)))
+        return session_core.family_forward(plan, qq, x, mats,
+                                           return_bn_stats=True)
+
+    shape = lambda a: _sds(one_chip, np.shape(a), a.dtype)  # noqa: E731
+    return jax.jit(fwd).lower(
+        _sds(one_chip, (n, f_in), jnp.float32), arrs,
+        [(shape(t.packed), shape(t.scale)) for t in q]).compile()
+
+
+def test_sage_reddit_forward_fits_v5e(one_chip):
+    """The ``sage-reddit`` full forward on the jnp path (232,965 nodes, 602
+    features, hidden 256, 41 classes; the 9,078,090 groups of
+    ``make_dataset("reddit", seed=0)``'s mean adjacency) fits one v5e with
+    room: its aggregations run 70 blocks at width 256 and 35 at width 41,
+    where one gather of every group at width 256 would ask for 297 GB."""
+    compiled = _forward_compile(one_chip, "sage", 232_965, 602, 256, 41,
+                                9_078_090, "mean", True)
+    # each loop carries its adjacency's tiles cut into blocks of groups
+    text = compiled.as_text()
+    assert "u16[70,131072,8]" in text and "u16[35,262144,8]" in text
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 12e9
+
+
+@pytest.mark.parametrize("n,groups", [(89_250, 112_973), (89_252, 131_072)],
+                         ids=["full", "bucket"])
+def test_saint_flickr_forward_is_one_block(one_chip, n, groups):
+    """The ``saint-flickr`` forwards, over the whole graph (112,973 groups)
+    and at the subgraph bucket (89,252 nodes, 131,072 groups), aggregate
+    in one block each, with no loop in the compiled program."""
+    compiled = _forward_compile(one_chip, "saint", n, 500, 256, 7, groups,
+                                "sum", False)
+    assert "while" not in compiled.as_text()
